@@ -16,6 +16,7 @@ import time
 sys.path.insert(0, "src")
 
 from repro.core import LustreCluster                       # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.models.config import ModelConfig, RunConfig     # noqa: E402
 from repro.train.trainer import Trainer, TrainerConfig     # noqa: E402
 
@@ -38,6 +39,7 @@ def main():
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cluster = LustreCluster(osts=4, mdses=1, clients=2, ost_failover=True,
                             commit_interval=64)

@@ -20,12 +20,44 @@ checkpoints live on Lustre. Design:
 from __future__ import annotations
 
 import json
+from functools import partial
 from typing import Any
 
+import jax
 import numpy as np
 
+from repro.core.ptlrpc import RpcError, TimeoutError_
 from repro.fsio.client import FsError, LustreClient
 from repro.kernels import ops as kops
+
+
+# what a dead or deactivated OST can raise on the read path; any other
+# error (a bug, a device or kernel failure) propagates instead of being
+# retried as a parity reconstruction
+_LOST = (FsError, RpcError, TimeoutError_)
+
+
+# Device leaves come to the host, and parity is computed, this many bytes
+# at a time, so the host holds at most two copies of the leaf being saved.
+SAVE_PIECE_BYTES = 64 << 20
+
+
+@partial(jax.jit, static_argnums=2)
+def _device_piece(x, start, n):
+    return jax.lax.dynamic_slice_in_dim(x.reshape(-1), start, n)
+
+
+def _host_bytes(leaf) -> bytes:
+    """The leaf's bytes. A device array is copied to the host a piece at
+    a time, through temporary slices on the device: `np.asarray` on the
+    leaf itself would cache a host copy of the whole leaf on it for as
+    long as the trainer keeps the leaf."""
+    if not isinstance(leaf, jax.Array):
+        return np.ascontiguousarray(leaf).tobytes()
+    size = leaf.size
+    n = max(1, SAVE_PIECE_BYTES // leaf.dtype.itemsize)
+    return b"".join(np.asarray(_device_piece(leaf, a, min(n, size - a)))
+                    for a in range(0, size, n))
 
 
 def _leaf_paths(tree, prefix=()):
@@ -97,8 +129,11 @@ class CheckpointManager:
 
     def save(self, step: int, tree: Any, *, extra_meta: dict | None = None
              ) -> dict:
-        """Write one checkpoint. Returns the manifest."""
-        leaves = [(p, np.asarray(v)) for p, v in _leaf_paths(tree)]
+        """Write one checkpoint. Returns the manifest.
+
+        Leaves may be device arrays: each is copied to the host a piece
+        at a time as its file is written (`_host_bytes`)."""
+        leaves = list(_leaf_paths(tree))
         d = self._step_dir(step)
         # overwrite semantics: a re-save of the same step (two trainers
         # resumed from one checkpoint) replaces the old content
@@ -115,23 +150,23 @@ class CheckpointManager:
             self.fs.enable_wbc(d)
         manifest = {"step": step, "leaves": {}, **(extra_meta or {})}
 
-        def write_leaf(w_idx: int, name: str, arr: np.ndarray):
+        def write_leaf(w_idx: int, name: str, leaf):
             fs = self.clients[w_idx % len(self.clients)]
             qmeta = None
-            if self.quantize == "int8" and arr.dtype.kind == "f" \
-                    and arr.size >= 256:
-                q, scales, blk = _quant_int8(arr)
+            if self.quantize == "int8" and leaf.dtype.kind == "f" \
+                    and leaf.size >= 256:
+                q, scales, blk = _quant_int8(np.asarray(leaf))
                 data = scales.tobytes() + q.tobytes()
                 qmeta = {"block": blk, "n_scales": len(scales),
-                         "orig_dtype": str(arr.dtype)}
+                         "orig_dtype": str(leaf.dtype)}
             else:
-                data = arr.tobytes()
+                data = _host_bytes(leaf)
             fh = fs.creat(f"{d}/{name}.bin",
                           stripe_count=self.stripe_count,
                           stripe_size=self.stripe_size)
             fs.write(fh, data, gid=1 + w_idx)       # group locks (ch.10.10)
             fs.close(fh)
-            entry = {"shape": list(arr.shape), "dtype": str(arr.dtype),
+            entry = {"shape": list(leaf.shape), "dtype": str(leaf.dtype),
                      "bytes": len(data), "writer": w_idx % len(self.clients)}
             if qmeta:
                 entry["quant"] = qmeta
@@ -166,16 +201,25 @@ class CheckpointManager:
         return manifest
 
     def _parity_for(self, fh, data: bytes) -> bytes:
-        """XOR parity across the file's stripe columns (Pallas kernel)."""
+        """XOR parity across the file's stripe columns (Pallas kernel),
+        a run of whole stripe rounds at a time: each run's parity is the
+        matching run of the whole file's."""
         lsm = fh.lsm
         ssz, cnt = lsm.stripe_size, lsm.stripe_count
         if cnt < 2:
             return kops.parity_bytes([data])
-        cols = [data[i * ssz:(i + 1) * ssz]
-                for i in range(-(-len(data) // ssz))]
-        rows = [b"".join(cols[i::cnt]) for i in range(cnt)]
-        rows = [r for r in rows if r]
-        return kops.parity_bytes(rows)
+        rnd = ssz * cnt
+        step = max(1, SAVE_PIECE_BYTES // rnd) * rnd
+        view = memoryview(data)
+        out = []
+        for a in range(0, len(data), step):
+            piece = view[a:a + step]
+            ncols = -(-len(piece) // ssz)
+            rows = [b"".join(piece[j * ssz:(j + 1) * ssz]
+                             for j in range(i, ncols, cnt))
+                    for i in range(cnt)]
+            out.append(kops.parity_bytes([r for r in rows if r]))
+        return b"".join(out)
 
     @staticmethod
     def _parity_ost(fh) -> int:
@@ -225,7 +269,7 @@ class CheckpointManager:
                 fs.close(fh)
                 if len(data) != e["bytes"]:
                     raise FsError(-5, "short read")
-            except (FsError, Exception) as ex:
+            except _LOST:
                 if not e.get("parity"):
                     raise
                 data = self._reconstruct(fs, d, name, e)
@@ -255,7 +299,7 @@ class CheckpointManager:
                 osc = fs.lov.by_uuid[o["ost"]]
                 sz = lov_mod.Lov._obj_size_for(lsm, i, total)
                 rows.append(osc.read(o["group"], o["oid"], 0, sz))
-            except Exception:
+            except _LOST:
                 if missing is not None:
                     raise FsError(-5, "more than one stripe lost")
                 missing = i

@@ -729,6 +729,12 @@ class Node:
         # service time starts at request arrival (the reply transmit below
         # then departs no earlier than this).
         self.sim.clock.advance_to(ev.arrival_time)
+        # the service owns the request from here on, so its slot in the
+        # pre-posted buffer (appended by NI.deliver just before this
+        # handler ran) is freed, as ptlrpc reposts a request buffer once
+        # its requests are handled. Kept, the buffer would hold every
+        # request the node ever received, bulk payloads included.
+        ev.md.buffer.pop()
         req, reply_nid, reply_portal = ev.data
         target_uuid = req.body.get("_target", "")
         target = self.targets.get(target_uuid)

@@ -1,8 +1,9 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On the CPU container the kernels run in interpret mode (the kernel body is
-executed op-by-op for correctness); on TPU they compile for real. Callers
-use these wrappers and never touch `interpret` directly.
+On the CPU backend the kernels run in interpret mode (the kernel body is
+executed op-by-op for correctness); on TPU they compile for real. Any
+other backend is an error. Callers use these wrappers and never touch
+`interpret` directly.
 """
 from __future__ import annotations
 
@@ -14,7 +15,11 @@ from repro.kernels import parity as _par
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(f"Pallas kernels run on tpu (or interpreted on "
+                           f"cpu), not on {backend!r}")
+    return backend == "cpu"
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, scale=None,

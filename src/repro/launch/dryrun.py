@@ -22,7 +22,6 @@ from repro.configs import get_config
 from repro.launch import cells as cells_mod
 from repro.launch.mesh import make_production_mesh
 from repro.models.config import SHAPES
-from repro.parallel import shardings as sh
 from repro.tools import hlo_cost, roofline
 from repro.train import steps as steps_mod
 
@@ -38,7 +37,6 @@ def run_cell(arch: str, shape: str, multi_pod: bool, rc_overrides=None,
         rc = dataclasses.replace(rc, **rc_overrides)
     mesh = make_production_mesh(multi_pod=multi_pod)
     n_chips = mesh.devices.size
-    sh.set_ambient_mesh(mesh)
     t0 = time.time()
     bundle = steps_mod.build_step(cfg, rc, mesh)
     with mesh:

@@ -10,6 +10,7 @@ product divides it (GSPMD can pad, but we keep in/out shardings exact).
 """
 from __future__ import annotations
 
+import contextlib
 import math
 from typing import Any, Sequence
 
@@ -84,31 +85,26 @@ def constrain(x: jax.Array, logical: Sequence) -> jax.Array:
     """with_sharding_constraint against the ambient mesh, divisibility-aware.
 
     Safe to call outside jit/mesh context (returns x unchanged)."""
-    mesh = _ambient_mesh()
+    mesh = _MESH[0]
     if mesh is None or mesh.empty:
         return x
     spec = resolve_spec(mesh, logical, x.shape)
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
-def _ambient_mesh() -> Mesh | None:
-    try:
-        m = jax.sharding.get_abstract_mesh()
-        if m is not None and not m.empty:
-            # need the concrete mesh for NamedSharding; use thread-local
-            pass
-    except Exception:
-        pass
-    return _MESH[0]
-
-
-# The dry-run / trainer set this before tracing so model-internal constraints
-# can resolve against the right physical mesh.
+# The mesh model-internal constraints resolve against. Step builders set it
+# only while their step function is traced (`ambient_mesh`), so a later
+# trace that has no mesh (e.g. BatchedServer) never sees a stale one.
 _MESH: list[Mesh | None] = [None]
 
 
-def set_ambient_mesh(mesh: Mesh | None) -> None:
-    _MESH[0] = mesh
+@contextlib.contextmanager
+def ambient_mesh(mesh: Mesh | None):
+    prev, _MESH[0] = _MESH[0], mesh
+    try:
+        yield mesh
+    finally:
+        _MESH[0] = prev
 
 
 def get_ambient_mesh() -> Mesh | None:

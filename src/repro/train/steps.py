@@ -134,6 +134,14 @@ def loss_fn(cfg: ModelConfig, params, batch, rc: RunConfig):
     return loss
 
 
+def _under_mesh(mesh, fn):
+    """`fn` with `mesh` ambient while it is traced (see sh.ambient_mesh)."""
+    def traced(*args):
+        with sh.ambient_mesh(mesh):
+            return fn(*args)
+    return traced
+
+
 # ----------------------------------------------------------------- train
 
 def _param_specs(cfg, rc, defs, mesh, pdt):
@@ -203,13 +211,16 @@ def build_train_step(cfg: ModelConfig, rc: RunConfig, mesh,
         return new_params, new_opt, metrics
 
     fn = jax.jit(
-        step,
+        _under_mesh(mesh, step),
         in_shardings=(param_specs, opt_specs, bspecs),
         out_shardings=(param_specs, opt_specs,
                        {"loss": scalar, "grad_norm": scalar}),
         donate_argnums=(0, 1),
     )
 
+    # jitted with the step's shardings, so each device builds only its
+    # own shard and no leaf lands whole on one device first
+    @partial(jax.jit, out_shardings=(param_specs, opt_specs))
     def init(key):
         params = L.tree_init(defs, key, pdt)
         return params, adamw.init_state(params)
@@ -237,7 +248,7 @@ def build_prefill_step(cfg: ModelConfig, rc: RunConfig, mesh) -> StepBundle:
 
     cache_specs = _cache_shardings(cfg, mesh, _prefill_cache_structs(cfg, rc))
     tok_spec = sh.named(mesh, ("batch", None), (rc.global_batch, 1))
-    fn = jax.jit(step, in_shardings=(param_specs, bspecs),
+    fn = jax.jit(_under_mesh(mesh, step), in_shardings=(param_specs, bspecs),
                  out_shardings=(tok_spec, cache_specs))
     return StepBundle(fn, (param_structs, bstructs),
                       (param_specs, bspecs), None)
@@ -306,7 +317,7 @@ def build_serve_step(cfg: ModelConfig, rc: RunConfig, mesh) -> StepBundle:
         next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return next_tok, new_cache
 
-    fn = jax.jit(step,
+    fn = jax.jit(_under_mesh(mesh, step),
                  in_shardings=(param_specs, cache_specs, tok_spec, scalar),
                  out_shardings=(tok_spec, cache_specs),
                  donate_argnums=(1,))

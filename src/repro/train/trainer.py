@@ -20,6 +20,7 @@ training job:
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any
 
 import jax
@@ -30,10 +31,7 @@ from repro.core.cluster import LustreCluster
 from repro.data import TokenDataset, TokenPipeline
 from repro.fsio import LustreClient
 from repro.launch.mesh import make_host_mesh
-from repro.models import layers as L
-from repro.models import registry
 from repro.models.config import ModelConfig, RunConfig
-from repro.parallel import shardings as sh
 from repro.train import steps as steps_mod
 
 
@@ -57,7 +55,6 @@ class Trainer:
         self.cluster = cluster
         self.cfg = cfg
         self.mesh = mesh if mesh is not None else make_host_mesh()
-        sh.set_ambient_mesh(self.mesh)
         self.bundle = steps_mod.build_train_step(cfg.model, cfg.rc, self.mesh)
         # storage clients: writer 0 is also the data-plane reader
         n_clients = len(cluster.client_nodes)
@@ -126,22 +123,25 @@ class Trainer:
             if fail_at and self.step in fail_at:
                 fail_at[self.step](self.cluster)
             batch = self._batch(self.step)
-            self.params, self.opt_state, m = self.bundle.fn(
-                self.params, self.opt_state, batch)
+            t0 = time.perf_counter()
+            self.params, self.opt_state, m = jax.block_until_ready(
+                self.bundle.fn(self.params, self.opt_state, batch))
             self.step += 1
             rec = {"step": self.step, "loss": float(m["loss"]),
-                   "grad_norm": float(m["grad_norm"])}
+                   "grad_norm": float(m["grad_norm"]),
+                   "step_s": time.perf_counter() - t0}
             self.metrics.append(rec)
             if self.step % self.cfg.ckpt_every == 0 or self.step == end:
+                t0 = time.perf_counter()
                 self.save_checkpoint()
+                rec["save_s"] = time.perf_counter() - t0
         return self.metrics
 
     # ---------------------------------------------------------- checkpoint
     def _state_tree(self) -> dict:
-        return {"params": jax.tree.map(np.asarray, self.params),
-                "opt": {"step": np.asarray(self.opt_state["step"]),
-                        "m": jax.tree.map(np.asarray, self.opt_state["m"]),
-                        "v": jax.tree.map(np.asarray, self.opt_state["v"])}}
+        """The state to checkpoint, as device arrays: the save copies
+        them to the host a piece at a time."""
+        return {"params": self.params, "opt": self.opt_state}
 
     def save_checkpoint(self):
         self.ckpt.save(self.step, self._state_tree(),
@@ -156,17 +156,13 @@ class Trainer:
         t.ckpt.cleanup_incomplete()
         flat, manifest = t.ckpt.restore()
         t.step = manifest["step"]
-        defs = registry.param_defs(cfg.model)
-        pdt = cfg.rc.param_dtype
 
         param_structs, opt_structs, _ = t.bundle.arg_structs
         pspecs, ospecs, _ = t.bundle.in_shardings
 
         def build(prefix, structs, specs):
-            # jax.tree.leaves_with_path only exists in newer jax;
-            # tree_util has carried it for much longer
-            leaves_s = jax.tree_util.tree_leaves_with_path(structs)
-            leaves_p = jax.tree_util.tree_leaves_with_path(specs)
+            leaves_s = jax.tree.leaves_with_path(structs)
+            leaves_p = jax.tree.leaves_with_path(specs)
             out_leaves = []
             for (path, s), (_, spec) in zip(leaves_s, leaves_p):
                 name = prefix + ".".join(

@@ -103,6 +103,7 @@ def test_elastic_resume_across_mesh_shapes():
         from repro.core import LustreCluster
         from repro.configs import get_smoke_config
         from repro.models.config import RunConfig
+        from repro.launch.mesh import make_mesh
         from repro.train.trainer import Trainer, TrainerConfig
 
         cluster = LustreCluster(osts=2, mdses=1, clients=2,
@@ -113,12 +114,12 @@ def test_elastic_resume_across_mesh_shapes():
                          attn_impl="ref"),
             n_steps=4, ckpt_every=2, dataset_seqs=64, n_writers=1,
             parity=False)
-        mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+        mesh_a = make_mesh((4, 2), ("data", "model"))
         tr = Trainer(cluster, cfg, mesh=mesh_a)
         tr.run(4)
         want = jax.tree.map(np.asarray, tr.params)
 
-        mesh_b = jax.make_mesh((2, 4), ("data", "model"))   # ELASTIC
+        mesh_b = make_mesh((2, 4), ("data", "model"))   # ELASTIC
         tr2 = Trainer.resume(cluster, cfg, mesh=mesh_b)
         assert tr2.step == 4
         got = jax.tree.map(np.asarray, tr2.params)

@@ -143,3 +143,14 @@ def test_parity_bytes_odd_sizes_roundtrip():
             surv = [pad[j] for j in range(len(chunks)) if j != miss]
             back = ops.reconstruct_bytes(surv, p, sizes[miss])
             assert back == chunks[miss], sizes
+
+
+@pytest.mark.parametrize("backend,interpret", [
+    ("cpu", True), ("tpu", False), ("gpu", None)])
+def test_kernels_interpret_only_on_cpu(monkeypatch, backend, interpret):
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError):
+            ops._interpret()
+    else:
+        assert ops._interpret() is interpret

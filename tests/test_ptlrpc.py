@@ -39,6 +39,21 @@ def test_request_timeout_advances_clock_and_recovers():
     assert osc.read(0, oid, 0, 10) == b"x" * 10
 
 
+def test_handled_requests_leave_no_payload_in_request_buffers():
+    """A server's pre-posted request buffer keeps nothing once the
+    service has the request: a node would otherwise hold every request
+    it ever received, bulk write payloads included."""
+    c, rpc, osc = mk()
+    oid = osc.create(0)["oid"]
+    for i in range(20):
+        osc.write(0, oid, i << 16, b"x" * (1 << 16))
+    node = c.ost_targets[0].node
+    mds = list(node.ni.portals.values())
+    assert mds and all(not me.md.buffer
+                       for p in mds for me in p.match_list)
+    assert osc.read(0, oid, 19 << 16, 4) == b"xxxx"
+
+
 def test_reply_cache_answers_resend_of_executed_update():
     c, rpc, osc = mk()
     oid = osc.create(0)["oid"]

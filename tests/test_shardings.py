@@ -89,8 +89,6 @@ def test_flops_scan_multiplied_by_trip_count():
     assert rep.n_while == 1
     # XLA's own analysis undercounts the loop (this is WHY hlo_cost exists)
     xla = compiled.cost_analysis()
-    if isinstance(xla, list):                 # older jax returns a list
-        xla = xla[0] if xla else None
     if xla and xla.get("flops"):
         assert xla["flops"] <= rep.flops
 
@@ -111,3 +109,30 @@ def test_shape_bytes_parser():
     assert hlo_cost.shape_bytes("bf16[2,2]") == 8
     assert hlo_cost.shape_bytes("(f32[4], s32[2])") == 24
     assert hlo_cost.shape_bytes("token[]") == 0
+
+
+def test_ambient_mesh_only_while_a_step_traces():
+    """A step builder's mesh is ambient while its step is traced and
+    gone afterwards, so a later trace without a mesh sees none."""
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh()
+    seen = []
+
+    def step(x):
+        seen.append(sh.get_ambient_mesh())
+        return sh.constrain(x * 2, ("batch", None))
+
+    from repro.train.steps import _under_mesh
+    out = jax.jit(_under_mesh(mesh, step))(jnp.ones((4, 2)))
+    assert seen == [mesh] and sh.get_ambient_mesh() is None
+    assert (np.asarray(out) == 2).all()
+    with sh.ambient_mesh(mesh):
+        assert sh.get_ambient_mesh() is mesh
+    assert sh.get_ambient_mesh() is None
+
+
+def test_host_mesh_axes_are_auto():
+    from jax.sharding import AxisType
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh()
+    assert mesh.axis_types == (AxisType.Auto, AxisType.Auto)
