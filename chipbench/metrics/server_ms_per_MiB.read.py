@@ -1,0 +1,7 @@
+"""cProfile self time of ptlrpc, NRS, OST, obd and the rest of core per MiB
+read."""
+from chipbench import readers
+
+
+def read(run):
+    return readers.layer_ms_per_mib(run, "server", "read_bytes")

@@ -1,0 +1,48 @@
+"""`raid5_ior_easy_degraded_read` driven end to end at a tiny size on the
+CPU, the chip gate skipped: a sound run is correct, and a run with the
+timed path broken underneath is not, for each fault the cell can have and
+for its control. A traced run reports the host layers' metrics and the
+idle breakdown."""
+import pytest
+
+from chipbench import faults
+from chipbench.tests import tiny
+
+WORKLOAD = "raid5_ior_easy_degraded_read"
+
+
+def test_sound_run_is_correct():
+    r = tiny.run(WORKLOAD)
+    assert r["correct"], r["checks"]
+    assert r["failed"] == 0 and r["attempted"] > 0
+    assert r["compiles"]["in_window"] == 0
+    assert all(c["value"] == 0 for c in r["checks"].values())
+    assert "setup_s" in r["metrics"]
+    assert list(r)[-1] == "checks"
+
+
+@pytest.mark.parametrize("plant", [
+    "half_reads_left_out",
+    "parity_altered",
+    "raid5_no_reconstruct"])
+def test_broken_path_is_not_correct(plant):
+    r = tiny.run(WORKLOAD, plant=faults.named(plant))
+    assert not r["correct"]
+    assert any(c["value"] > c["limit"] for c in r["checks"].values()) \
+        or r["failed"]
+
+
+def test_traced_run_reports_per_layer_metrics():
+    r = tiny.run(WORKLOAD, trace=True)
+    assert r["correct"], r["checks"]
+    # host layers are read on any backend; device shares only on a TPU
+    assert any(k.startswith("client_ms_per_MiB") for k in r["metrics"])
+    assert not any(k.startswith(("device_idle", "parity_roofline"))
+                   for k in r["metrics"])
+    assert r["device"]["window_s"] > 0
+    assert r["breakdown"]["idle_gaps"]
+    # each half runs whole passes, so the second reads on fresh mounts
+    per_pass = 4 * tiny.RAID5["config"]["file_bytes_per_rank"] // \
+        tiny.RAID5["traffic"]["transfer_bytes"]
+    assert r["attempted"] >= 2 * per_pass
+    assert r["attempted"] % per_pass == 0
