@@ -1,0 +1,8 @@
+"""Time in the program's `kernel.put` (copy in, dispatch) and `kernel.get`
+(wait, copy back) spans per MiB read, in the traced part."""
+from chipbench import progspans, readers
+
+
+def read(run):
+    return progspans.ms_per(run, ("kernel.put", "kernel.get"),
+                            "read_bytes", readers.MiB)

@@ -1,0 +1,7 @@
+"""Self time of the program's `rpc.serve` spans (the server handlers, NRS,
+OST and obd beneath ptlrpc) per MiB written, in the traced part."""
+from chipbench import progspans, readers
+
+
+def read(run):
+    return progspans.ms_per(run, ("rpc.serve",), "write_bytes", readers.MiB)

@@ -26,6 +26,7 @@ from typing import Any
 import jax
 import numpy as np
 
+from repro.core import metrics
 from repro.core.ptlrpc import RpcError, TimeoutError_
 from repro.fsio.client import FsError, LustreClient
 from repro.kernels import ops as kops
@@ -47,6 +48,7 @@ def _device_piece(x, start, n):
     return jax.lax.dynamic_slice_in_dim(x.reshape(-1), start, n)
 
 
+@metrics.spanned("ckpt.d2h")
 def _host_bytes(leaf) -> bytes:
     """The leaf's bytes. A device array is copied to the host a piece at
     a time, through temporary slices on the device: `np.asarray` on the

@@ -27,6 +27,7 @@ import dataclasses
 import itertools
 from typing import Optional
 
+from repro.core import metrics as metrics_mod
 from repro.core import osc as osc_mod
 from repro.core import ptlrpc as R
 
@@ -233,6 +234,7 @@ class Lov:
     def _osc(self, lsm: StripeMd, sidx: int) -> osc_mod.Osc:
         return self.by_uuid[lsm.objects[sidx]["ost"]]
 
+    @metrics_mod.spanned("lov.io")
     def write(self, lsm: StripeMd, offset: int, data: bytes,
               gid: int = 0) -> int:
         """Striped write: logical runs are grouped per stripe object and
@@ -256,6 +258,7 @@ class Lov:
                            for s, v in by_stripe.items()])
         return len(data)
 
+    @metrics_mod.spanned("lov.io")
     def read(self, lsm: StripeMd, offset: int, length: int) -> bytes:
         """Striped read: one vectored OST_READ per stripe object, issued
         concurrently; partial results are merged by logical position."""

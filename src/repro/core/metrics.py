@@ -19,10 +19,29 @@ makes replay-after-crash count once, not twice.
 All times are **virtual-clock** seconds; histogram buckets are log2-
 spaced microseconds (bucket i covers (2^(i-1), 2^i] µs), which keeps a
 histogram ~50 ints wide no matter how many samples it absorbs.
+
+Host spans are the other kind of record here: **wall-clock** spans of the
+host's own work at each layer boundary (`span`, `spanned`, `add`), kept
+only while the JAX profiler records and written into its trace as well,
+so that they share the device trace's clock. They serve the on-chip
+benchmark; the virtual-clock spans above serve operators.
 """
 from __future__ import annotations
 
+import functools
 import math
+import time
+
+try:
+    # jax.profiler.TraceAnnotation is this class with nothing added;
+    # importing it from jaxlib keeps jax itself out of the simulator
+    from jaxlib._profiler import TraceMe as _TraceMe
+    profiling = _TraceMe.is_enabled
+except ImportError:                    # no jaxlib: nothing can trace
+    _TraceMe = None
+
+    def profiling() -> bool:
+        return False
 
 
 class LatencyHistogram:
@@ -218,3 +237,123 @@ def merge_jobid_histograms(target_summaries: list[dict]) -> dict:
                 m = merged[jobid] = LatencyHistogram()
             m.merge(h)
     return {jobid: h.summary() for jobid, h in sorted(merged.items())}
+
+
+# -------------------------------------------------------------- host spans
+
+SPAN_PREFIX = "lustre/"
+SPAN_CAPACITY = 1 << 20
+_now = time.perf_counter_ns
+
+
+class _NoSpan:
+    """What `span` gives while the profiler is off: one shared object that
+    reads no clock and records nothing."""
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NO_SPAN = _NoSpan()
+
+
+class _Span:
+    __slots__ = ("rec", "name", "trace", "idx")
+
+    def __init__(self, rec: "HostSpans", name: str):
+        self.rec, self.name = rec, name
+
+    def __enter__(self):
+        # the profiler's event encloses the recorded interval
+        self.trace = _TraceMe(SPAN_PREFIX + self.name)
+        self.trace.__enter__()
+        rec = self.rec
+        parent = rec._open[-1] if rec._open else -1
+        if len(rec._events) < rec.capacity:
+            self.idx = len(rec._events)
+            rec._events.append([self.name, _now(), 0, parent, {}])
+        else:
+            self.idx = -1
+            rec.dropped += 1
+        rec._open.append(self.idx)
+        return self
+
+    def __exit__(self, *exc):
+        t1 = _now()
+        rec = self.rec
+        rec._open.pop()
+        if self.idx >= 0:
+            rec._events[self.idx][2] = t1
+        self.trace.__exit__(None, None, None)
+        return False
+
+
+class HostSpans:
+    """Bounded in-memory buffer of wall-clock host spans.
+
+    An event is (name, start_ns, end_ns, parent, counts): `perf_counter_ns`
+    times, the index of the enclosing span's event (-1 for none) and the
+    counts `add`ed while it was the innermost open span. Spans are
+    recorded only while the JAX profiler records; past `capacity` events a
+    span is counted in `dropped` instead. The program runs on one thread,
+    so one stack of open spans serves it.
+
+    While the profiler is off a span costs one `profiling()` check: a
+    decorated call adds the decorator's frame, `span` returns one shared
+    no-op, and a call site guards its `add` with `profiling()` itself, so
+    that not even the count is computed."""
+
+    def __init__(self, capacity: int = SPAN_CAPACITY):
+        self.capacity = capacity
+        self.dropped = 0
+        self._events: list = []
+        self._open: list[int] = []
+
+    def span(self, name: str):
+        """Context manager around one layer's work. While the profiler is
+        off it returns a shared no-op and does nothing else."""
+        if not profiling():
+            return _NO_SPAN
+        return _Span(self, name)
+
+    def spanned(self, name: str):
+        """Decorator: every call of the function runs inside span(name)."""
+        def wrap(fn):
+            @functools.wraps(fn)
+            def inner(*args, **kwargs):
+                if not profiling():
+                    return fn(*args, **kwargs)
+                with _Span(self, name):
+                    return fn(*args, **kwargs)
+            return inner
+        return wrap
+
+    def add(self, key: str, n: int):
+        """Add n to the count `key` of the innermost open span, if any."""
+        if self._open and self._open[-1] >= 0:
+            counts = self._events[self._open[-1]][4]
+            counts[key] = counts.get(key, 0) + n
+
+    def read(self) -> tuple[list, int]:
+        """(events, dropped): every span recorded so far, in the order
+        opened, so that a parent index is a position in the list; a span
+        still open has end 0."""
+        return ([(n, a, b, p, dict(c)) for n, a, b, p, c in self._events],
+                self.dropped)
+
+    def clear(self):
+        if self._open:
+            raise RuntimeError("host spans are open")
+        self._events.clear()
+        self.dropped = 0
+
+
+HOST_SPANS = HostSpans()
+span = HOST_SPANS.span
+spanned = HOST_SPANS.spanned
+add = HOST_SPANS.add
+read_spans = HOST_SPANS.read

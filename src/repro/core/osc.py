@@ -29,6 +29,7 @@ from typing import Optional
 
 from repro.core import dlm as dlm_mod
 from repro.core import fail as fail_mod
+from repro.core import metrics as metrics_mod
 from repro.core import ptlrpc as R
 
 PAGE_SIZE = 4096
@@ -239,6 +240,7 @@ class Osc:
         if self.grant == 0:
             self.grant = self.imp.connect_data.get("grant", 0)
 
+    @metrics_mod.spanned("osc.io")
     def write(self, group: int, oid: int, offset: int, data: bytes,
               *, lock: bool = True, gid: int = 0):
         if not data:
@@ -265,6 +267,7 @@ class Osc:
         return self._write_through(
             DirtyExtent(group, oid, offset, bytes(data), self.sim.now))
 
+    @metrics_mod.spanned("osc.io")
     def writev(self, group: int, oid: int, iov: list, *, lock: bool = True,
                gid: int = 0):
         """Vectored write: iov = [(offset, data), ...] for ONE object.
@@ -312,6 +315,8 @@ class Osc:
             self.dirty.append(DirtyExtent(group, oid, offset, bytes(data),
                                           self.sim.now))
             self.dirty_bytes += len(data)
+            if metrics_mod.profiling():
+                metrics_mod.add("copied", len(data))
             return
         end = offset + len(data)
         touch = [d for d in self.dirty
@@ -334,6 +339,8 @@ class Osc:
             self.sim.stats.count("osc.extents_coalesced", len(touch))
         self.dirty.append(merged)
         self.dirty_bytes += len(merged.data)
+        if metrics_mod.profiling():
+            metrics_mod.add("copied", len(merged.data))
 
     # ------------------------------------------------------- clean cache
     def _note_write(self, group: int, oid: int, offset: int, nbytes: int):
@@ -374,6 +381,8 @@ class Osc:
         exts.append(merged)
         exts.sort(key=lambda e: e.offset)
         self.clean_bytes += len(merged.data)
+        if metrics_mod.profiling():
+            metrics_mod.add("copied", len(merged.data))
         self._clean_shrink()
 
     def _clean_shrink(self):
@@ -511,6 +520,7 @@ class Osc:
         self._note_written_size(d.group, d.oid, rep.data)
         return rep.data
 
+    @metrics_mod.spanned("osc.io")
     def flush(self, group=None, oid=None):
         """Write back dirty extents (all, or one object's), coalesced into
         vectored BRW RPCs under in-flight flow control. Flushed pages are
@@ -586,6 +596,7 @@ class Osc:
                 return d.data[o:o + length]
         return None
 
+    @metrics_mod.spanned("osc.io")
     def read(self, group: int, oid: int, offset: int, length: int,
              *, lock: bool = True, from_cobd: str | None = None) -> bytes:
         # serve from own dirty cache when fully covered
@@ -616,6 +627,7 @@ class Osc:
             self._clean_insert(group, oid, offset, data)
         return data
 
+    @metrics_mod.spanned("osc.io")
     def readv(self, group: int, oid: int, iov: list,
               *, lock: bool = True) -> list[bytes]:
         """Vectored read: iov = [(offset, length), ...] for ONE object.

@@ -49,6 +49,7 @@ from collections import defaultdict
 from typing import Any, Callable, Optional
 
 from repro.core import fail as fail_mod
+from repro.core import metrics as metrics_mod
 from repro.core import nrs as nrs_mod
 from repro.core import portals as P
 from repro.core import sanitize
@@ -290,6 +291,8 @@ class Service:
     def request_cost(self, req: Request) -> float:
         return self.cost_parts(req)[0]
 
+    # a nested server-to-server RPC is a child of the span serving it
+    @metrics_mod.spanned("rpc.serve")
     def process(self, req: Request, arrival: float) -> Reply:
         cost, seeks, nio_bytes = self.cost_parts(req)
         start = self.policy.schedule(req, arrival, cost)

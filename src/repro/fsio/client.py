@@ -34,6 +34,7 @@ from repro.core import lov as lov_mod
 from repro.core import mdc as mdc_mod
 from repro.core import osc as osc_mod
 from repro.core import mds as mds_mod
+from repro.core import metrics as metrics_mod
 from repro.core import ptlrpc as R
 from repro.core import recovery as rec_mod
 from repro.core.cluster import LustreCluster
@@ -529,6 +530,7 @@ class LustreClient:
         self.handles[id(fh)] = fh
         return fh
 
+    @metrics_mod.spanned("fs.io")
     def write(self, fh: FileHandle, data: bytes, offset: int | None = None,
               gid: int = 0) -> int:
         if fh.lsm is None:
@@ -541,6 +543,7 @@ class LustreClient:
         self.sim.stats.add_bytes("fs.write", n)
         return n
 
+    @metrics_mod.spanned("fs.io")
     def read(self, fh: FileHandle, length: int,
              offset: int | None = None) -> bytes:
         if fh.lsm is None:

@@ -10,6 +10,7 @@ from __future__ import annotations
 import jax
 import numpy as np
 
+from repro.core import metrics
 from repro.kernels import flash_attention as _fa
 from repro.kernels import parity as _par
 
@@ -43,8 +44,10 @@ def reconstruct(survivors, parity, *, block=4096):
 
 
 # ------------------------------------------------------- byte helpers
+@metrics.spanned("kernel.parity")
 def parity_bytes(chunks: list[bytes]) -> bytes:
-    """XOR parity over equal-length byte chunks (pads the tail)."""
+    """XOR parity over equal-length byte chunks (pads the tail). Its span's
+    self time is the packing and unpacking on the host."""
     n = max(len(c) for c in chunks)
     n4 = -(-n // 4) * 4
     arr = np.zeros((len(chunks), n4 // 4), np.int32)
@@ -52,8 +55,20 @@ def parity_bytes(chunks: list[bytes]) -> bytes:
         buf = np.zeros(n4, np.uint8)
         buf[:len(c)] = np.frombuffer(c, np.uint8)
         arr[i] = buf.view(np.int32)
-    out = np.asarray(xor_parity(jax.numpy.asarray(arr)))
+    out = _get(_put(arr))
     return out.view(np.uint8).tobytes()[:n]
+
+
+@metrics.spanned("kernel.put")
+def _put(arr: np.ndarray) -> jax.Array:
+    """The copy to the device and the kernel's dispatch."""
+    return xor_parity(jax.numpy.asarray(arr))
+
+
+@metrics.spanned("kernel.get")
+def _get(dev: jax.Array) -> np.ndarray:
+    """The wait for the kernel and the copy back."""
+    return np.asarray(dev)
 
 
 def reconstruct_bytes(survivors: list[bytes], parity: bytes,

@@ -52,6 +52,7 @@ def xor_parity(blocks: jax.Array, *, block: int = 4096,
         out_specs=pl.BlockSpec((block,), lambda j: (j,)),
         out_shape=jax.ShapeDtypeStruct((padded,), jnp.int32),
         interpret=interpret,
+        name="xor_parity",
     )(blocks)
     return out[:N] if padded != N else out
 

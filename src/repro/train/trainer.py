@@ -27,6 +27,7 @@ import jax
 import numpy as np
 
 from repro.ckpt import CheckpointManager
+from repro.core import metrics
 from repro.core.cluster import LustreCluster
 from repro.data import TokenDataset, TokenPipeline
 from repro.fsio import LustreClient
@@ -85,6 +86,7 @@ class Trainer:
         return self
 
     # ---------------------------------------------------------------- data
+    @metrics.spanned("train.batch")
     def _batch(self, step: int) -> dict:
         toks = self.pipeline.batch_at(step)
         b = {"tokens": jax.numpy.asarray(toks)}
