@@ -19,12 +19,17 @@ an OST. It owns:
     `max_cached_mb`, and are served with ZERO RPCs for as long as a
     cached PR/PW lock covers them. Lock revocation (blocking AST),
     cancel, and eviction invalidate the covered pages — cached data is
-    valid exactly while the lock protocol says it is.
+    valid exactly while the lock protocol says it is. Like a page cache,
+    it keeps extents as they were inserted (never coalesced): an insert
+    or an invalidation trims only the extents it overlaps, and a read
+    over a run of adjacent extents joins just the requested bytes.
 """
 from __future__ import annotations
 
+import bisect
 import dataclasses
-from collections import defaultdict
+import operator
+from collections import OrderedDict, defaultdict
 from typing import Optional
 
 from repro.core import dlm as dlm_mod
@@ -56,21 +61,23 @@ class DirtyExtent:
         return self.offset + len(self.data)
 
 
-@dataclasses.dataclass
+@dataclasses.dataclass(eq=False)
 class CleanExtent:
     """A lock-covered cached extent of clean data (read or written-back).
     Validity is NOT stored here: it is re-checked against the client lock
     cache on every hit (the pages are usable exactly while a cached PR/PW
-    lock covers them)."""
+    lock covers them). Hashed by identity: it is its own LRU key."""
     group: int
     oid: int
     offset: int
     data: bytes
-    atime: float                       # LRU clock
 
     @property
     def end(self) -> int:
         return self.offset + len(self.data)
+
+
+_offset = operator.attrgetter("offset")     # bisect key of CleanExtent
 
 
 class Osc:
@@ -95,8 +102,9 @@ class Osc:
         self.dirty: list[DirtyExtent] = []
         self.dirty_bytes = 0
         # clean read cache: per-object sorted disjoint extents, global
-        # LRU byte budget (max_cached_mb)
+        # LRU byte budget (max_cached_mb); _lru runs least recent first
         self.clean: dict[tuple, list[CleanExtent]] = defaultdict(list)
+        self._lru: OrderedDict[CleanExtent, None] = OrderedDict()
         self.clean_bytes = 0
         self.max_cached_bytes = max(0, max_cached_mb) << 20
         # size/mtime known-under-lock (LVB, §7.7): valid while a cached
@@ -169,6 +177,7 @@ class Osc:
         self.dirty.clear()
         self.dirty_bytes = 0
         self.clean.clear()
+        self._lru.clear()
         self.clean_bytes = 0
         self._sizes.clear()
         self._mtimes.clear()
@@ -357,90 +366,135 @@ class Osc:
 
     def _clean_insert(self, group: int, oid: int, offset: int,
                       data: bytes):
-        """Cache a clean extent, coalescing with overlapping/adjacent
-        cached extents (new data wins), then enforce the LRU byte budget."""
+        """Cache a clean extent as it comes, then enforce the LRU byte
+        budget. Older extents it overlaps are trimmed to what lies outside
+        it (new data wins); adjacent ones are left alone. `bytes` are kept
+        by reference; anything else is copied once, since cached pages
+        must not change under the cache."""
         if not data or not self.max_cached_bytes:
             return
+        if type(data) is not bytes:
+            data = bytes(data)
         key = (group, oid)
-        end = offset + len(data)
         exts = self.clean[key]
-        touch = [e for e in exts if e.offset <= end and offset <= e.end]
-        if not touch:
-            merged = CleanExtent(group, oid, offset, bytes(data),
-                                 self.sim.now)
-        else:
-            lo = min(offset, min(e.offset for e in touch))
-            hi = max(end, max(e.end for e in touch))
-            buf = bytearray(hi - lo)
-            for e in touch:
-                buf[e.offset - lo:e.end - lo] = e.data
-                exts.remove(e)
-                self.clean_bytes -= len(e.data)
-            buf[offset - lo:end - lo] = data
-            merged = CleanExtent(group, oid, lo, bytes(buf), self.sim.now)
-        exts.append(merged)
-        exts.sort(key=lambda e: e.offset)
-        self.clean_bytes += len(merged.data)
+        end = offset + len(data)
+        i, _ = self._clean_cut(exts, offset, end)
+        e = CleanExtent(group, oid, offset, data)
+        exts.insert(i, e)
+        self._lru[e] = None
+        self.clean_bytes += len(data)
         if metrics_mod.profiling():
-            metrics_mod.add("copied", len(merged.data))
+            metrics_mod.add("copied", len(data))
         self._clean_shrink()
+
+    def _clean_cut(self, exts: list[CleanExtent], lo: int,
+                   hi: int) -> tuple[int, int]:
+        """Remove [lo, hi) from one object's sorted extents: an extent
+        inside it goes, one that straddles an edge keeps (a copy of) the
+        part outside. A trimmed extent keeps its LRU place; the right half
+        of a split one enters as most recent. Returns the index where
+        [lo, hi) now belongs and the number of extents it touched."""
+        i = bisect.bisect_right(exts, lo, key=_offset)
+        if i and exts[i - 1].end > lo:
+            i -= 1
+        j = i
+        while j < len(exts) and exts[j].offset < hi:
+            j += 1
+        if i == j:
+            return i, 0
+        keep, copied = [], 0
+        for e in exts[i:j]:
+            self.clean_bytes -= len(e.data)
+            left = e.data[:lo - e.offset] if e.offset < lo else b""
+            right = e.data[hi - e.offset:] if e.end > hi else b""
+            if left:
+                e.data = left
+                keep.append(e)
+                if right:
+                    r = CleanExtent(e.group, e.oid, hi, right)
+                    self._lru[r] = None
+                    keep.append(r)
+            elif right:
+                e.offset, e.data = hi, right
+                keep.append(e)
+            else:
+                del self._lru[e]
+            copied += len(left) + len(right)
+        exts[i:j] = keep
+        self.clean_bytes += copied
+        if copied and metrics_mod.profiling():
+            metrics_mod.add("copied", copied)
+        return i + (1 if keep and keep[0].offset < lo else 0), j - i
 
     def _clean_shrink(self):
         """LRU-evict whole extents until the cache fits max_cached_mb."""
         while self.clean_bytes > self.max_cached_bytes:
-            victim = min((e for exts in self.clean.values() for e in exts),
-                         key=lambda e: e.atime)
+            victim, _ = self._lru.popitem(last=False)
             vkey = (victim.group, victim.oid)
-            self.clean[vkey].remove(victim)
-            if not self.clean[vkey]:
+            exts = self.clean[vkey]
+            del exts[bisect.bisect_left(exts, victim.offset, key=_offset)]
+            if not exts:
                 del self.clean[vkey]
             self.clean_bytes -= len(victim.data)
             self.sim.stats.count("osc.cache_lru_evict")
 
     def _clean_read(self, group: int, oid: int, offset: int,
                     length: int) -> bytes | None:
-        """Serve from the clean cache iff a cached PR/PW lock covers the
-        extent (the §7.4 validity rule) — zero RPCs on a hit."""
+        """Serve from the clean cache iff one extent, or a run of adjacent
+        extents with no gap, holds the range and a cached PR/PW lock
+        covers it (the §7.4 validity rule) — zero RPCs on a hit."""
         exts = self.clean.get((group, oid))
         if not exts:
             return None
         end = offset + length
-        for e in exts:
-            if e.offset <= offset and end <= e.end:
-                if self.locks.match(self._res(group, oid), "PR",
-                                    (offset, end)) is None:
-                    # no covering lock: the pages are unprotected — a
-                    # revocation should already have dropped them, but
-                    # never serve unguarded data (count + drop)
-                    self.sim.stats.count("osc.cache_uncovered")
-                    self._invalidate_clean(group, oid, (e.offset, e.end))
-                    return None
-                e.atime = self.sim.now
-                self.sim.stats.count("osc.cache_hit")
-                self.sim.stats.count("osc.cache_hit_bytes", length)
-                o = offset - e.offset
-                return e.data[o:o + length]
-        return None
+        i = j = bisect.bisect_right(exts, offset, key=_offset) - 1
+        if i < 0:
+            return None
+        pos = exts[i].end
+        while pos < end:
+            j += 1
+            if j == len(exts) or exts[j].offset != pos:
+                return None
+            pos = exts[j].end
+        if self.locks.match(self._res(group, oid), "PR",
+                            (offset, end)) is None:
+            # no covering lock: the pages are unprotected — a revocation
+            # should already have dropped them, but never serve unguarded
+            # data (count + drop)
+            self.sim.stats.count("osc.cache_uncovered")
+            self._invalidate_clean(group, oid, (exts[i].offset, pos))
+            return None
+        self.sim.stats.count("osc.cache_hit")
+        self.sim.stats.count("osc.cache_hit_bytes", length)
+        first = exts[i]
+        if i == j:
+            self._lru.move_to_end(first)
+            o = offset - first.offset
+            return first.data[o:o + length]
+        run = exts[i:j + 1]
+        for e in run:
+            self._lru.move_to_end(e)
+        parts = [memoryview(e.data) for e in run]
+        parts[0] = parts[0][offset - first.offset:]
+        parts[-1] = parts[-1][:end - run[-1].offset]
+        if metrics_mod.profiling():
+            metrics_mod.add("copied", length)
+        return b"".join(parts)
 
     def _invalidate_clean(self, group: int, oid: int,
                           extent: tuple | None = None):
-        """Drop clean pages overlapping `extent` (None = whole object)."""
+        """Drop clean pages inside `extent` (None = whole object); pages
+        of the object outside it stay cached."""
         key = (group, oid)
         exts = self.clean.get(key)
         if not exts:
             return
         lo, hi = extent if extent is not None else (0, dlm_mod.MAX_EXT)
-        keep = []
-        for e in exts:
-            if e.offset < hi and lo < e.end:
-                self.clean_bytes -= len(e.data)
-                self.sim.stats.count("osc.cache_invalidate")
-            else:
-                keep.append(e)
-        if keep:
-            self.clean[key] = keep
-        else:
-            self.clean.pop(key, None)
+        _, touched = self._clean_cut(exts, lo, hi)
+        if touched:
+            self.sim.stats.count("osc.cache_invalidate", touched)
+        if not exts:
+            del self.clean[key]
 
     # ------------------------------------------------------- BRW engine
     def _pack(self, items: list, nbytes_of) -> list[list]:

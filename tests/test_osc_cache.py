@@ -392,3 +392,163 @@ def test_cache_stats_in_procfs():
     assert cc["hits"] >= 1
     assert 0.0 <= cc["hit_rate"] <= 1.0
     assert "osc.cache_hit" in p["counters"]
+
+
+# ------------------------------------ page-cache extents (no coalescing)
+
+def _copied_under_profiler(tmp_path, fn):
+    """Run fn with the JAX profiler on; return the `copied` count of the
+    `osc.io` spans it recorded."""
+    import jax
+    from repro.core import metrics
+    metrics.HOST_SPANS.clear()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert metrics.profiling()
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    events, dropped = metrics.read_spans()
+    metrics.HOST_SPANS.clear()
+    assert dropped == 0
+    return sum(c.get("copied", 0) for n, _, _, _, c in events
+               if n == "osc.io")
+
+
+def test_sequential_inserts_copy_each_byte_once(tmp_path):
+    """N sequential adjacent inserts add N units to `copied`; coalescing
+    each into one growing extent added 1 + 2 + ... + N."""
+    c = mk()
+    w = c.make_oscs(c.make_client_rpc(0), writeback=False)[0]
+    oid = w.create(0)["oid"]
+    unit, n = 16 << 10, 16
+    data = bytes(range(256)) * (unit * n // 256)
+    w.write(0, oid, 0, data)
+    r = c.make_oscs(c.make_client_rpc(1))[0]
+    out = []
+    copied = _copied_under_profiler(tmp_path, lambda: out.extend(
+        r.read(0, oid, i * unit, unit) for i in range(n)))
+    assert b"".join(out) == data
+    assert copied == n * unit
+    base = reads(c)
+    assert r.read(0, oid, 0, len(data)) == data        # joined, no RPC
+    assert reads(c) == base
+
+
+def test_insert_over_two_older_extents_new_data_wins():
+    c = mk()
+    osc = c.make_oscs(c.make_client_rpc(0))[0]
+    oid = osc.create(0)["oid"]
+    osc.write(0, oid, 0, b"a" * 4096)
+    osc.flush()
+    osc.write(0, oid, 4096, b"b" * 4096)
+    osc.flush()
+    osc._clean_insert(0, oid, 2048, b"n" * 4096)
+    base = reads(c)
+    assert osc.read(0, oid, 0, 8192) == \
+        b"a" * 2048 + b"n" * 4096 + b"b" * 2048
+    assert reads(c) == base
+    assert osc.clean_bytes == 8192             # trimmed, nothing doubled
+    exts = osc.clean[(0, oid)]
+    assert [(e.offset, e.end) for e in exts] == \
+        [(0, 2048), (2048, 6144), (6144, 8192)]
+
+
+def test_read_spanning_adjacent_extents_is_a_hit():
+    c = mk()
+    osc = c.make_oscs(c.make_client_rpc(0))[0]
+    oid = osc.create(0)["oid"]
+    osc.write(0, oid, 0, b"a" * 4096)
+    osc.flush()
+    osc.write(0, oid, 4096, b"b" * 4096)
+    osc.flush()
+    assert len(osc.clean[(0, oid)]) == 2       # kept as inserted
+    base, hits = reads(c), c.stats.counters["osc.cache_hit"]
+    assert osc.read(0, oid, 2048, 4096) == b"a" * 2048 + b"b" * 2048
+    assert reads(c) == base
+    assert c.stats.counters["osc.cache_hit"] == hits + 1
+
+
+def test_invalidating_a_subrange_keeps_the_rest_cached():
+    c = mk()
+    w = c.make_oscs(c.make_client_rpc(0), writeback=False)[0]
+    oid = w.create(0)["oid"]
+    data = bytes(range(256)) * 256            # 64 KiB
+    w.write(0, oid, 0, data)
+    r = c.make_oscs(c.make_client_rpc(1))[0]
+    assert r.read(0, oid, 0, len(data)) == data        # one extent
+    r.write(0, oid, 16 << 10, b"w" * (16 << 10))       # supersedes 16-32K
+    base = reads(c)
+    assert r.read(0, oid, 0, 16 << 10) == data[:16 << 10]
+    assert r.read(0, oid, 32 << 10, 32 << 10) == data[32 << 10:]
+    assert reads(c) == base                    # the rest still hits
+    r.flush()
+    assert r.read(0, oid, 0, len(data)) == \
+        data[:16 << 10] + b"w" * (16 << 10) + data[32 << 10:]
+    assert reads(c) == base                    # flushed pages joined in
+    r.punch(0, oid, 48 << 10)                  # drops [48K, end) only
+    assert r.read(0, oid, 0, 48 << 10) == \
+        data[:16 << 10] + b"w" * (16 << 10) + data[32 << 10:48 << 10]
+    assert reads(c) == base
+
+
+def test_lru_evicts_least_recently_used_extent_first():
+    c = mk(max_cached_mb=1)
+    osc = c.make_oscs(c.make_client_rpc(0))[0]
+    oid = osc.create(0)["oid"]
+    chunk = 256 << 10
+    offs = [i * 2 * chunk for i in range(5)]   # gaps: never adjacent
+    for i, off in enumerate(offs[:4]):         # A B C D fill 1 MiB
+        osc.write(0, oid, off, bytes([i]) * chunk)
+        osc.flush()
+    assert osc.clean_bytes == 1 << 20
+    base = reads(c)
+    assert osc.read(0, oid, offs[0], chunk) == bytes([0]) * chunk  # A used
+    osc.write(0, oid, offs[4], bytes([4]) * chunk)                 # E
+    osc.flush()
+    assert c.stats.counters["osc.cache_lru_evict"] == 1
+    for i in (0, 2, 3, 4):                     # B went, the others stay
+        assert osc.read(0, oid, offs[i], chunk) == bytes([i]) * chunk
+    assert reads(c) == base
+    assert osc.read(0, oid, offs[1], chunk) == bytes([1]) * chunk
+    assert reads(c) == base + 1
+
+
+def test_raid5_overwrite_rmw_reads_hit_the_writers_cache():
+    """A raid5 half-round overwrite reads the round's other units back:
+    the client that wrote them serves those reads from its clean cache
+    (a parity write no longer drops the object's other units), and the
+    OSTs' data and parity still match the numpy RAID-5 reference."""
+    import numpy as np
+    from chipbench.reference import raid5 as ref
+    ssz, k, rounds = 16 << 10, 4, 4
+    rng = np.random.default_rng(7)
+    v1, v2 = (rng.integers(0, 256, rounds * k * ssz, dtype=np.uint8)
+              for _ in range(2))
+
+    def overwrite(same_client):
+        c = LustreCluster(osts=5, mdses=1, clients=2, commit_interval=256)
+        w = LustreClient(c, 0).mount()
+        fh = w.creat("/f", stripe_count=k, stripe_size=ssz,
+                     pattern="raid5")
+        xfer = 2 * ssz                         # half a round
+        for off in range(0, v1.size, xfer):
+            w.write(fh, v1[off:off + xfer].tobytes(), offset=off)
+        w.close(fh)
+        fs = w if same_client else LustreClient(c, 1).mount()
+        fh = fs.open("/f", "w")
+        cnt = c.stats.counters
+        h0, r0 = cnt.get("osc.cache_hit", 0), reads(c)
+        for off in range(0, v2.size, xfer):
+            fs.write(fh, v2[off:off + xfer].tobytes(), offset=off)
+        objs = [np.frombuffer(bytes(c.target(o["ost"]).obd.objects[
+            (o["group"], o["oid"])].data), np.uint8)
+            for o in fh.lsm.objects]
+        assert ref.compare_objects(v2, objs, ssz, k) == (0, 0)
+        return cnt.get("osc.cache_hit", 0) - h0, reads(c) - r0
+
+    warm_hits, warm_reads = overwrite(True)
+    cold_hits, cold_reads = overwrite(False)
+    assert warm_hits > cold_hits
+    assert warm_reads < cold_reads
+    assert warm_reads == 0                     # every RMW read hit
