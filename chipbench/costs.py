@@ -1,4 +1,5 @@
-"""Operations and bytes the algorithms need, from shapes and counts.
+"""Bytes the kernels need, from shapes and counts. A model's FLOPs are
+in its architecture's file, `archs/<architecture>.py`.
 
 The XOR parity kernel reads K rows of N int32 words and writes one row:
 (K + 1) * N * 4 bytes, no arithmetic worth counting, so it is bound by
@@ -44,20 +45,3 @@ def ckpt_parity_kernel_bytes(nbytes: int, stripe_size: int,
         total += xor_kernel_bytes(len(cols), max(cols))
     return total
 
-
-def train_flops_per_token(cfg: dict, seq_len: int) -> float:
-    """Model FLOPs per trained token, forward and backward (3x forward):
-    every matmul of the layers and of the (tied) head, and causal
-    attention's score and value products. Recomputation is not counted.
-    `cfg` uses the published config's key names."""
-    d = cfg["hidden_size"]
-    h, kv, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                 cfg["head_dim"])
-    ff, v, n = cfg["intermediate_size"], cfg["vocab_size"], \
-        cfg["num_hidden_layers"]
-    proj = d * h * hd + 2 * d * kv * hd + h * hd * d
-    mlp = 3 * d * ff
-    # causal: each query sees on average (seq_len + 1) / 2 keys
-    attn = 2 * h * hd * (seq_len + 1) / 2
-    fwd = 2 * (n * (proj + mlp) + d * v) + 2 * n * attn
-    return 3.0 * fwd

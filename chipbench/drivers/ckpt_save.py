@@ -13,7 +13,7 @@ import json
 
 import numpy as np
 
-from chipbench import costs, model
+from chipbench import costs, model, registry
 from chipbench.reference import stripes
 
 
@@ -25,7 +25,7 @@ def shard_shapes(cfg: dict) -> dict:
     from repro.models import layers as L
     from repro.models import registry as mreg
     dp = cfg["training"]["fsdp_data_parallel"]
-    defs = mreg.param_defs(model.model_config(cfg))
+    defs = mreg.param_defs(registry.arch(cfg).model_config(cfg))
     specs = L.tree_specs(defs, AbstractMesh((dp, 1), ("data", "model")),
                          fsdp=True)
     shapes = jax.tree.map(lambda d, s: s.shard_shape(d.shape), defs, specs,

@@ -2,10 +2,11 @@
 
 FAULTS are what a cell's comparison must catch, each cell those it can
 have: an operation that leaves the state as it was, half of the work left
-out, and an answer or a token altered where it is produced. CONTROLS are
-the shortcuts a later change might be tempted to take, each breaking a
-guarantee the cell's configuration states. Each is a set of replacements for
-`patches.replaced`, planted for the window only (`harness.run_cell`).
+out, the exchange between chips left out, and an answer or a token altered
+where it is produced. CONTROLS are the shortcuts a later change might be
+tempted to take, each breaking a guarantee the cell's configuration
+states. Each is a set of replacements for `patches.replaced`, planted for
+the window only (`harness.run_cell`).
 """
 from __future__ import annotations
 
@@ -76,6 +77,42 @@ def _half_batch(orig):
     return loss_fn
 
 
+def _exchange_left_out(orig):
+    # FSDP without the gradients' exchange between chips: each chip
+    # updates its shard of a leaf with the gradient of the mean loss over
+    # its own block of the batch alone (a leaf no chip shards takes chip
+    # 0's); the loss reported is still the mean over the whole batch
+    def loss_fn(cfg, params, batch, rc):
+        import jax
+        import jax.numpy as jnp
+        from repro.models import layers as L
+        from repro.models import registry as mreg
+        from repro.parallel import shardings as sh
+        mesh = sh.get_ambient_mesh()
+        n = mesh.shape["data"]
+        specs = L.tree_specs(mreg.param_defs(cfg), mesh, fsdp=True)
+        per = batch["tokens"].shape[0] // n
+
+        def own(x, spec, k):
+            dims = [i for i, a in enumerate(spec.spec) if a == "data"]
+            if not dims:
+                return x if k == 0 else jax.lax.stop_gradient(x)
+            shard = jax.lax.broadcasted_iota(jnp.int32, x.shape, dims[0]) \
+                // (x.shape[dims[0]] // n)
+            return jnp.where(shard == k, x, jax.lax.stop_gradient(x))
+
+        total = mean = 0.0
+        for k in range(n):
+            lk = orig(cfg, jax.tree.map(lambda x, s: own(x, s, k), params,
+                                        specs),
+                      {key: v[k * per:(k + 1) * per]
+                       for key, v in batch.items()}, rc)
+            total = total + lk
+            mean = mean + jax.lax.stop_gradient(lk) / n
+        return total - jax.lax.stop_gradient(total) + mean
+    return loss_fn
+
+
 def _token_altered(orig):
     def batch_at(self, step):
         out = orig(self, step)
@@ -99,6 +136,8 @@ FAULTS = {
         "repro.optim.adamw:apply_updates": _step_unchanged},
     "half_batch_left_out": {
         "repro.train.steps:loss_fn": _half_batch},
+    "exchange_left_out": {
+        "repro.train.steps:loss_fn": _exchange_left_out},
     "token_altered": {
         "repro.data.pipeline:TokenPipeline.batch_at": _token_altered},
 }

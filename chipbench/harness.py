@@ -243,6 +243,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     meter = dev.CompileMeter(jax)
 
     cell = cell_cls(config, traffic, seed)
+    cell.chips = wl["chips"]
     # a cell whose checked work runs in set-up has a planted fault there too
     setup_plant = plant if getattr(cell, "checked_in_setup", False) else None
     with patches.replaced(setup_plant or {}):
@@ -274,7 +275,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     in_window = compile_after["compiles"] - compile_setup["compiles"]
     mem = dev.memory_peak_bytes(devices)
     cell.finish()
+    t_verify = time.perf_counter()
     checks = cell.verify()
+    verify_s = time.perf_counter() - t_verify
 
     attempted = sum(len(p.ops) + p.failed for p in parts.values())
     failed = sum(p.failed for p in parts.values())
@@ -305,6 +308,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     result["notes"] = {**getattr(cell, "notes", {}), **op_notes(parts),
                        "window_s": sum(p.seconds for p in parts.values()),
                        "gc_s": gc_clock.seconds, "gc_full": gc_clock.full,
+                       "verify_s": verify_s,
                        "host_after_window": mem_after}
     log(f"notes {result['notes']}")
     result["checks"] = {name: {"value": v, "limit": lim}

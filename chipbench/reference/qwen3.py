@@ -1,5 +1,5 @@
-"""Qwen3 decoder-only language model, its loss, gradients and AdamW, in
-plain jax.numpy at float32, every matmul at the highest precision.
+"""Qwen3 decoder-only language model, its loss and gradients, in plain
+jax.numpy at float32, every matmul at the highest precision.
 
 Follows the published architecture (Qwen3ForCausalLM): token embedding;
 per layer a pre-norm grouped-query attention with RMSNorm on each query
@@ -109,12 +109,3 @@ def loss_and_grads(cfg_items: tuple, params, tokens):
     gnorm = jnp.sqrt(sum(jnp.sum(g * g) for g in jax.tree.leaves(grads)))
     return val, grads, gnorm
 
-
-@functools.partial(jax.jit, donate_argnums=(0, 2, 3))
-def adamw_leaf(p, g, m, v, *, lr, scale, bc1, bc2, b1, b2, eps, wd):
-    """One AdamW step of one leaf, its gradient scaled by `scale` (the
-    global-norm clip)."""
-    g = g * scale
-    m = b1 * m + (1 - b1) * g
-    v = b2 * v + (1 - b2) * g * g
-    return p - lr * ((m / bc1) / (jnp.sqrt(v / bc2) + eps) + wd * p), m, v

@@ -3,8 +3,11 @@
 A configuration is `configs/<name>.json` (named by the entry's `file`), a
 traffic mix is `traffic/<name>.json`, the code that drives a mix is
 `drivers/<kind>.py` for the `kind` the mix names, and a metric's reader
-is `metrics/<metric name>.py`. Adding a cell or a metric adds files and
-entries; nothing here changes.
+is `metrics/<metric name>.py`. What depends on a model configuration's
+architecture (its model config for the program, FLOPs a token, plain
+reference, norm leaves) is `archs/<architectures[0]>.py`. Adding a cell,
+a metric or an architecture adds files and entries; nothing here
+changes.
 """
 from __future__ import annotations
 
@@ -76,6 +79,13 @@ def _load(path: Path, what: str):
 def driver(kind: str):
     """The module that drives traffic of this kind (`drivers/<kind>.py`)."""
     return _load(HERE / "drivers" / f"{_checked(kind)}.py", "driver")
+
+
+def arch(cfg: dict):
+    """The module of a model configuration's architecture
+    (`archs/<architectures[0]>.py`)."""
+    return _load(HERE / "archs" / f"{_checked(cfg['architectures'][0])}.py",
+                 "arch")
 
 
 def reader(metric: str):

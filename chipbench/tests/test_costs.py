@@ -1,4 +1,5 @@
-"""Kernel bytes and model FLOPs against hand counts."""
+"""Kernel bytes and model FLOPs (the architecture's, `archs/`) against
+hand counts."""
 import json
 
 from chipbench import costs, registry
@@ -42,6 +43,6 @@ def test_train_flops_per_token_qwen3_4b_l5():
     matmul = 2 * (n * per_layer + d * v)
     attn = n * 4 * 32 * 128 * (seq + 1) / 2
     want = 3 * (matmul + attn)
-    assert costs.train_flops_per_token(cfg, seq) == want
+    assert registry.arch(cfg).train_flops_per_token(cfg, seq) == want
     # about 6 x 894M parameters
     assert 5.3e9 < want < 5.5e9

@@ -17,6 +17,24 @@ def test_every_cell_resolves():
         assert hasattr(registry.driver(tr["kind"]), "Cell")
 
 
+def test_every_model_configuration_has_its_architecture():
+    for c in BENCH["configs"]:
+        cfg = registry.config(BENCH, c["name"])
+        if "architectures" in cfg:
+            arch = registry.arch(cfg)
+            assert callable(arch.model_config)
+            assert callable(arch.train_flops_per_token)
+            assert callable(arch.loss_and_grads)
+
+
+def test_missing_architecture_file_is_named():
+    with pytest.raises(registry.UnknownName,
+                       match="chipbench/archs/NoSuchForCausalLM.py"):
+        registry.arch({"architectures": ["NoSuchForCausalLM"]})
+    with pytest.raises(registry.UnknownName):
+        registry.arch({"architectures": ["../model"]})
+
+
 def test_every_metric_has_a_reader():
     for kind in ("end_to_end", "per_layer"):
         for m in BENCH[kind]:

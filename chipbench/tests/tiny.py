@@ -17,6 +17,7 @@ QWEN3_TRAIN = {"config": dict(QWEN3["config"], training={
 
 OVERRIDES = {"raid5_ior_easy_write": RAID5,
              "qwen3_4b_train": QWEN3_TRAIN,
+             "qwen3_4b_train_fsdp4": QWEN3_TRAIN,
              "raid5_ior_easy_degraded_read": RAID5,
              "qwen3_4b_ckpt_save": QWEN3}
 
