@@ -602,11 +602,16 @@ class Osc:
             self.sim.parallel([
                 (lambda dd=d: self._write_through(dd)) for d in todo])
         # drop from the cache only once the writes went out: a failed
-        # flush (ENOSPC, unreachable target) must not discard dirty data
+        # flush (ENOSPC, unreachable target) must not discard dirty data.
+        # Pages stay cached only under a lock, as reads cache them: a
+        # write that took none (a rebuild's) would leave pages no
+        # revocation can drop and no read may serve
         for d in todo:
             self.dirty.remove(d)
             self.dirty_bytes -= len(d.data)
-            self._clean_insert(d.group, d.oid, d.offset, d.data)
+            if self.locks.match(self._res(d.group, d.oid), "PR",
+                                (d.offset, d.end)) is not None:
+                self._clean_insert(d.group, d.oid, d.offset, d.data)
         if group is None:
             # full flush = the write burst is over: return idle grant so
             # the OST can redistribute it (ch. 10.12 grant shrinking —

@@ -64,6 +64,12 @@ class FileHandle:
     # opened through the metadata write-back cache: no MDS open handle,
     # close() records size/mtime as a cache record instead of an RPC
     wbc: bool = False
+    # where it was opened: the PR lock on that directory guards the
+    # layout `lsm` (Lustre's layout lock); once the lock is lost the
+    # layout is re-read before the next IO (`revalidate_layout`)
+    parent: Optional[tuple] = None
+    name: str = ""
+    layout_stale: bool = False
 
 
 @dataclasses.dataclass
@@ -299,11 +305,38 @@ class LustreClient:
 
     def _attrs_revoked(self, mdc, lk):
         """A dir lock left the MDC lock cache (blocking AST / cancel /
-        eviction): every attr it covered is unprotected — drop them."""
+        eviction): every attr it covered is unprotected — drop them —
+        and so are the layouts of the files open under it."""
         for fid in self._attr_by_lock.pop((id(mdc), lk.handle), ()):
             dropped = self.attr_cache.pop(fid, None)
             if dropped is not None:
                 self.sim.stats.count("fs.attr_invalidate")
+        self._layouts_unguarded(tuple(lk.res_name[1:]), parent=True)
+
+    def _layouts_unguarded(self, fid: tuple, *, parent: bool = False):
+        """Mark the open handles of file `fid` (with `parent`, of every
+        file in directory `fid`) to re-read their layouts. Only a raid5
+        layout changes under an open handle (`rebuild_ost` swaps in the
+        rebuilt object), so only raid5 handles pay the re-read."""
+        for fh in self.handles.values():
+            if (fh.parent if parent else fh.fid) == fid and not fh.wbc \
+                    and fh.lsm is not None and fh.lsm.pattern == "raid5":
+                fh.layout_stale = True
+
+    def revalidate_layout(self, fh: FileHandle):
+        """Re-read a handle's layout from the MDS, under a fresh PR lock on
+        its directory, once the lock that guarded it has been lost: a
+        layout swapped meanwhile (an OST's object rebuilt onto a spare)
+        takes effect at the handle's next IO. A file no longer found by
+        its name keeps the layout it had."""
+        fh.layout_stale = False
+        _, data = self.lmv.getattr_lock(fh.parent, fh.name, want_ea=True)
+        ea = data.get("ea") or {}
+        if data.get("status", 0) == 0 and "lov" in ea:
+            lsm = lov_mod.StripeMd.from_ea(ea["lov"])
+            if lsm != fh.lsm:
+                fh.lsm = lsm
+                self.sim.stats.count("fs.layout_refresh")
 
     def resolve(self, path: str, *, follow: bool = True,
                 _depth: int = 0) -> tuple:
@@ -486,7 +519,8 @@ class LustreClient:
             lsm = lov_mod.StripeMd.from_ea(ea["lov"])
         else:
             lsm = None
-        fh = FileHandle(fid, lsm, data.get("open_handle", 0), flags)
+        fh = FileHandle(fid, lsm, data.get("open_handle", 0), flags,
+                        parent=tuple(parent), name=name)
         self.handles[id(fh)] = fh
         return fh
 
@@ -533,6 +567,8 @@ class LustreClient:
     @metrics_mod.spanned("fs.io")
     def write(self, fh: FileHandle, data: bytes, offset: int | None = None,
               gid: int = 0) -> int:
+        if fh.layout_stale:
+            self.revalidate_layout(fh)
         if fh.lsm is None:
             raise FsError(-22, "no stripe md")
         off = fh.pos if offset is None else offset
@@ -546,6 +582,8 @@ class LustreClient:
     @metrics_mod.spanned("fs.io")
     def read(self, fh: FileHandle, length: int,
              offset: int | None = None) -> bytes:
+        if fh.layout_stale:
+            self.revalidate_layout(fh)
         if fh.lsm is None:
             raise FsError(-22, "no stripe md")
         off = fh.pos if offset is None else offset
@@ -618,6 +656,8 @@ class LustreClient:
         while the file was open for write). A WBC handle's size/mtime
         land as a setattr record instead — close is not a reintegration
         point (ch. 17), fsync and release are."""
+        if fh.layout_stale:
+            self.revalidate_layout(fh)
         self._fsync_data(fh)
         size = mtime = None
         if "w" in fh.flags or "c" in fh.flags:
@@ -1181,6 +1221,7 @@ class LustreClient:
                     {"type": "setattr", "fid": fid,
                      "ea": {"lov": new_lsm.to_ea()}})
                 self._attr_drop(fid)
+                self._layouts_unguarded(fid)   # our own handles too
                 self.sim.stats.count("lov.layout_swap")
                 report["swapped"] += 1
                 if limit is not None and report["rebuilt"] >= limit:

@@ -32,6 +32,30 @@ def flash_attention(q, k, v, *, causal=True, window=0, scale=None,
                                block_k=block_k, interpret=_interpret())
 
 
+def _gmm_tile(dim: int, cap: int) -> int:
+    """The largest multiple of 128 up to `cap` that divides `dim`, or the
+    whole dimension where none does."""
+    return next((t for t in range(cap, 0, -128) if dim % t == 0), dim)
+
+
+def _gmm_tiling(m: int, k: int, n: int) -> tuple[int, int, int]:
+    # rows in tiles of 256 (each expert's group wastes at most one partly
+    # filled tile); contraction and output tiles of at most 1152 columns,
+    # which keeps the rhs, lhs, output and f32 accumulator tiles of the
+    # forward (gmm) and the weight gradient (tgmm) in scoped VMEM
+    return _gmm_tile(m, 256), _gmm_tile(k, 1152), _gmm_tile(n, 1152)
+
+
+def gmm(lhs, rhs, group_sizes, *, out_dtype):
+    """Grouped matmul (JAX's Pallas megablox kernel, with its backward):
+    rows of `lhs` (m, k), sorted by group, times their group's `rhs`
+    (g, k, n). `group_sizes` may name groups beyond the g of `rhs`: their
+    rows are neither computed nor read, and come out as zeros."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops as mb
+    return mb.gmm(lhs, rhs, group_sizes, out_dtype, _gmm_tiling, None,
+                  None, False, _interpret())
+
+
 def xor_parity(blocks, *, block=4096):
     block = min(block, blocks.shape[1])
     return _par.xor_parity(blocks, block=block, interpret=_interpret())

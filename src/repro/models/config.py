@@ -28,6 +28,19 @@ class ModelConfig:
     top_k: int = 0
     d_ff_expert: int = 0
     capacity_factor: float = 1.25
+    # expert parallelism: this device holds experts [expert_offset,
+    # expert_offset + experts_held) of the n_experts the router chooses
+    # among, and computes their part of each token's result, dropless
+    # (0 = every expert, capacity-bounded dispatch)
+    experts_held: int = 0
+    expert_offset: int = 0
+    # YaRN on the full-attention layers (window 0); windowed layers keep
+    # the default RoPE (0 = default RoPE everywhere)
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: float = 1.0  # scales cos and sin
     # encoder-decoder (whisper) / VLM (paligemma) stub frontends
     enc_layers: int = 0
     enc_frames: int = 0              # precomputed frame embeddings (stub)
@@ -48,6 +61,11 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def experts_here(self) -> int:
+        """Experts whose weights this device holds."""
+        return self.experts_held or self.n_experts
 
     @property
     def n_params(self) -> int:

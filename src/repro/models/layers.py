@@ -90,12 +90,49 @@ def rms_norm(x, w, eps=1e-6):
 
 def rope(x, positions, theta: float):
     """x: (..., S, H, D) rotary over D; positions (..., S)."""
-    d = x.shape[-1]
-    half = d // 2
+    half = x.shape[-1] // 2
     freqs = jnp.arange(0, half, dtype=jnp.float32)
-    inv = theta ** (-freqs / half)
+    return rope_table(x, positions, theta ** (-freqs / half))
+
+
+def rope_inv_freq(head_dim: int, theta: float) -> np.ndarray:
+    """Default RoPE: one inverse frequency per pair of dims, (head_dim/2,)."""
+    half = head_dim // 2
+    return theta ** (-np.arange(half, dtype=np.float64) / half)
+
+
+def yarn_inv_freq(head_dim: int, theta: float, factor: float,
+                  original_max_pos: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0) -> np.ndarray:
+    """YaRN (arXiv:2309.00071), as HF's `_compute_yarn_parameters` with
+    `truncate`: frequencies that turn more than `beta_fast` times over the
+    original context keep their value, those that turn less than
+    `beta_slow` times are divided by `factor`, and a linear ramp over the
+    pair index blends the two in between."""
+    half = head_dim // 2
+    extra = rope_inv_freq(head_dim, theta)
+    inter = extra / factor
+
+    def pair_index(rotations):
+        return head_dim * np.log(original_max_pos / (
+            rotations * 2 * np.pi)) / (2 * np.log(theta))
+
+    low = max(np.floor(pair_index(beta_fast)), 0)
+    high = min(np.ceil(pair_index(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half) - low) / (high - low), 0, 1)
+    return inter * ramp + extra * (1 - ramp)
+
+
+def rope_table(x, positions, inv, scale=None):
+    """`rope` with the inverse frequencies given, (D/2,) float32, and cos
+    and sin multiplied by `scale` where given (YaRN's attention factor)."""
+    half = x.shape[-1] // 2
     ang = positions[..., None].astype(jnp.float32) * inv  # (..., S, half)
     sin, cos = jnp.sin(ang), jnp.cos(ang)
+    if scale is not None:
+        sin, cos = sin * scale, cos * scale
     sin = sin[..., None, :]  # broadcast over heads
     cos = cos[..., None, :]
     x1, x2 = x[..., :half], x[..., half:]

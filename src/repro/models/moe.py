@@ -6,7 +6,7 @@ are dense batched GEMMs with the *active* flop count (top_k * capacity_factor
 x dense-one-expert), unlike one-hot-einsum dispatch which pays all-experts
 flops.
 
-Two dispatch strategies:
+Two capacity-bounded dispatch strategies:
   * global (GSPMD): one logical (E, C, d) buffer; the cross-shard scatter
     makes XLA replicate + all-reduce it — simple but collective-heavy;
   * shard-local (shard_map, `rc.shard_moe_tokens`): activations are
@@ -15,6 +15,10 @@ Two dispatch strategies:
     the partial outputs — zero token movement, buffer is (E/mp, C_l, d).
     This is the production layout; EXPERIMENTS.md §Perf quantifies the
     delta against the global baseline.
+
+and, for a device told which experts it holds (`cfg.experts_held`), a
+dropless held-expert layer: `held_expert_ffn`, grouped matmuls over the
+(token, expert) pairs that fall to its experts.
 """
 from __future__ import annotations
 
@@ -31,7 +35,10 @@ def capacity(cfg, n_tokens: int) -> int:
 
 
 def moe_ffn(cfg, p, x, rc):
-    """x: (T, d) -> (T, d), aux load-balance loss (scalar)."""
+    """x: (T, d) -> (T, d), aux load-balance loss (scalar; a dict for
+    held experts, see `held_expert_ffn`)."""
+    if cfg.experts_held:
+        return held_expert_ffn(cfg, p, x, rc)
     if rc.shard_moe_tokens:
         mesh = get_ambient_mesh()
         if mesh is not None and "model" in mesh.axis_names \
@@ -165,3 +172,60 @@ def _moe_ffn_global(cfg, p, x, rc):
     w = top_p.reshape(-1)[order].astype(cdt)
     y = jnp.zeros((T, d), cdt).at[tok].add(gathered * w[:, None])
     return y, aux
+
+
+# ------------------------------------------------------------ held experts
+
+def route(cfg, x, router):
+    """Top-k routing over all n_experts: each token's k experts (T, k),
+    their softmax weights renormalized over the k, and the Switch
+    load-balance term. The router's product runs in float32 at full
+    precision, so that near ties split as the float32 reference splits
+    them wherever the inputs agree."""
+    E = cfg.n_experts
+    gates = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(gates, axis=-1)
+    top_p, top_e = jax.lax.top_k(probs, cfg.top_k)
+    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    me = jnp.mean(probs, axis=0)
+    ce = jnp.mean(jax.nn.one_hot(top_e[:, 0], E, dtype=jnp.float32), axis=0)
+    return top_p, top_e, E * jnp.sum(me * ce)
+
+
+def held_expert_ffn(cfg, p, x, rc):
+    """The part of each token's result that this device's experts give:
+    experts [expert_offset, expert_offset + experts_held) of the
+    n_experts the router chooses among (expert parallelism, without the
+    exchange). Every token is routed over all experts (softmax, top-k,
+    the k weights renormalized); the (token, expert) pairs that fall to a
+    held expert are sorted by expert and run through grouped matmuls
+    (`kernels.ops.gmm`), which compute each held expert's rows and none
+    of the others'. No pair is dropped, however unbalanced the routing.
+    Returns the weighted sum over the held pairs, and {"balance": the
+    Switch load-balance term over all experts, "held_pairs": the pairs
+    the held experts computed}."""
+    from repro.kernels import ops as kops
+    cdt = jnp.dtype(rc.compute_dtype)
+    T, d = x.shape
+    k, H, off = cfg.top_k, cfg.experts_held, cfg.expert_offset
+    top_p, top_e, aux = route(cfg, x, p["router"])
+
+    # group g < H is held expert off + g; group H, the pairs held elsewhere
+    flat_e = top_e.reshape(-1)
+    held = (flat_e >= off) & (flat_e < off + H)
+    group = jnp.where(held, flat_e - off, H)
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.zeros(H + 1, jnp.int32).at[group].add(1)
+    xs = x.astype(cdt)[order // k]                          # (T*k, d)
+    g = kops.gmm(xs, p["wg"].astype(cdt), sizes, out_dtype=cdt)
+    u = kops.gmm(xs, p["wu"].astype(cdt), sizes, out_dtype=cdt)
+    h = jax.nn.silu(g) * u
+    out = kops.gmm(h, p["wd"].astype(cdt), sizes, out_dtype=cdt)
+    # back to (token, choice) order; pairs held elsewhere weigh 0
+    out = out[jnp.argsort(order)].reshape(T, k, d)
+    w = jnp.where(held, top_p.reshape(-1), 0.0).reshape(T, k)
+    y = jnp.einsum("tkd,tk->td", out, w.astype(cdt),
+                   preferred_element_type=jnp.float32)
+    return y.astype(cdt), {"balance": aux,
+                           "held_pairs": jnp.sum(sizes[:H])}

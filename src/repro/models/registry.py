@@ -55,7 +55,8 @@ def count_params(cfg: ModelConfig, active_only: bool = False) -> int:
         if "embed" in keys or "dec_pos" in keys:
             continue
         n = math.prod(d.shape)
-        if cfg.is_moe and len(d.shape) == 4 and d.shape[1] == cfg.n_experts:
+        if cfg.is_moe and len(d.shape) == 4 \
+                and d.shape[1] == cfg.experts_here:
             if active_only:
                 n = n * cfg.top_k / cfg.n_experts
         total += n

@@ -47,10 +47,10 @@ def _mlp_defs(cfg: ModelConfig, n: int):
     d = cfg.d_model
     D = lambda *s, lg, init="normal": L.ParamDef((n, *s), (None, *lg), init)
     if cfg.is_moe:
-        E, f = cfg.n_experts, cfg.d_ff_expert
+        E, f = cfg.experts_here, cfg.d_ff_expert
         return {
             "ln": D(d, lg=(None,), init="zeros"),
-            "router": D(d, E, lg=(None, None)),
+            "router": D(d, cfg.n_experts, lg=(None, None)),
             "wg": D(E, d, f, lg=("model", None, None)),
             "wu": D(E, d, f, lg=("model", None, None)),
             "wd": D(E, f, d, lg=("model", None, None)),
@@ -96,6 +96,29 @@ def windows(cfg: ModelConfig) -> np.ndarray:
     elif cfg.sliding_window:
         w[:] = cfg.sliding_window
     return w
+
+
+def rope_tables(cfg: ModelConfig):
+    """Per-layer RoPE where it differs by layer type: (inverse frequencies
+    (n_layers, head_dim/2), cos/sin scale (n_layers,)), float32, with YaRN
+    on the full-attention layers and the default on windowed ones. None
+    where every layer uses the default RoPE."""
+    if not cfg.yarn_factor:
+        return None
+    base = L.rope_inv_freq(cfg.head_dim, cfg.rope_theta)
+    yarn = L.yarn_inv_freq(cfg.head_dim, cfg.rope_theta, cfg.yarn_factor,
+                           cfg.yarn_original_max_pos, cfg.yarn_beta_fast,
+                           cfg.yarn_beta_slow)
+    full = windows(cfg) == 0
+    inv = np.where(full[:, None], yarn[None], base[None])
+    return (inv.astype(np.float32),
+            np.where(full, cfg.yarn_attention_factor, 1.0).astype(np.float32))
+
+
+def _rope(cfg, x, positions, rope):
+    if rope is not None:
+        return L.rope_table(x, positions, *rope)
+    return L.rope(x, positions, cfg.rope_theta)
 
 
 # ----------------------------------------------------------------- blocks
@@ -147,16 +170,18 @@ def _chunked_attention(q, k, v, window, prefix_len, chunk, cdt,
     return out.transpose(1, 0, 2, 3, 4).reshape(B, Sq, H, Dh)
 
 
-def attn_block(cfg, p, x, window, prefix_len, rc, positions=None):
-    """Full-sequence self attention (train / prefill). Returns (x, (k, v))."""
+def attn_block(cfg, p, x, window, prefix_len, rc, positions=None,
+               rope=None):
+    """Full-sequence self attention (train / prefill). Returns (x, (k, v)).
+    `rope`: this layer's row of `rope_tables`, where layers differ."""
     cdt = jnp.dtype(rc.compute_dtype)
     B, S, _ = x.shape
     q, k, v = _qkv(cfg, p, x, cdt)
     if positions is None:
         positions = jnp.arange(S)[None, :]
     if cfg.rope_theta:
-        q = L.rope(q, positions, cfg.rope_theta)
-        k = L.rope(k, positions, cfg.rope_theta)
+        q = _rope(cfg, q, positions, rope)
+        k = _rope(cfg, k, positions, rope)
     k = constrain(k, ("batch", None, "model", None))
     v = constrain(v, ("batch", None, "model", None))
     if rc.attn_impl == "flash" and not prefix_len \
@@ -191,15 +216,16 @@ def cross_attn_block(cfg, p, x, enc_kv, rc):
     return _attn_out(cfg, p, out, x, cdt)
 
 
-def decode_attn_block(cfg, p, x, window, cache_k, cache_v, pos, rc):
+def decode_attn_block(cfg, p, x, window, cache_k, cache_v, pos, rc,
+                      rope=None):
     """One-token decode. cache_[kv]: (B, Smax, Hkv, Dh). Returns updated."""
     cdt = jnp.dtype(rc.compute_dtype)
     B = x.shape[0]
     q, k, v = _qkv(cfg, p, x, cdt)  # S == 1
     posv = jnp.full((B, 1), pos)
     if cfg.rope_theta:
-        q = L.rope(q, posv, cfg.rope_theta)
-        k = L.rope(k, posv, cfg.rope_theta)
+        q = _rope(cfg, q, posv, rope)
+        k = _rope(cfg, k, posv, rope)
     cache_k = jax.lax.dynamic_update_slice_in_dim(cache_k, k.astype(cache_k.dtype), pos, 1)
     cache_v = jax.lax.dynamic_update_slice_in_dim(cache_v, v.astype(cache_v.dtype), pos, 1)
     mask = L.decode_mask(cache_k.shape[1], pos, window)
@@ -274,9 +300,14 @@ def forward(cfg: ModelConfig, params, batch, rc, return_cache=False):
     x, prefix_len = _inputs_with_prefix(cfg, params, tokens, batch, rc)
     w_arr = windows(cfg)
     # uniform window -> keep it static (enables the flash kernel + avoids
-    # a per-layer where() in the HLO)
+    # a per-layer where() in the HLO); what differs by layer is scanned
     uniform = int(w_arr[0]) if (w_arr == w_arr[0]).all() else None
-    win = jnp.asarray(w_arr)
+    per_layer = {}
+    if uniform is None:
+        per_layer["window"] = jnp.asarray(w_arr)
+    tables = rope_tables(cfg)
+    if tables is not None:
+        per_layer["rope"] = tuple(jnp.asarray(t) for t in tables)
     enc_kv = None
     if cfg.enc_layers:
         enc_out = encoder_forward(cfg, params, batch["frames"], rc)
@@ -284,11 +315,9 @@ def forward(cfg: ModelConfig, params, batch, rc, return_cache=False):
         enc_kv = enc_out
 
     def body(x, sl):
-        if uniform is None:
-            pl, w = sl
-        else:
-            pl, w = sl, uniform
-        x, kv = attn_block(cfg, pl["attn"], x, w, prefix_len, rc)
+        pl, lay = sl if per_layer else (sl, {})
+        x, kv = attn_block(cfg, pl["attn"], x, lay.get("window", uniform),
+                           prefix_len, rc, rope=lay.get("rope"))
         xkv = None
         if cfg.enc_layers:
             cdt = x.dtype
@@ -302,7 +331,7 @@ def forward(cfg: ModelConfig, params, batch, rc, return_cache=False):
         out = (kv, xkv) if return_cache else None
         return x, (out, aux)
 
-    xs = params["layers"] if uniform is not None else (params["layers"], win)
+    xs = (params["layers"], per_layer) if per_layer else params["layers"]
     x, (cache, aux) = jax.lax.scan(_maybe_remat(body, rc), x, xs)
     x = L.rms_norm(x, params["final_ln"], cfg.norm_eps)
     if return_cache:
@@ -310,7 +339,7 @@ def forward(cfg: ModelConfig, params, batch, rc, return_cache=False):
         cache = {"k": k, "v": v}
         if cfg.enc_layers:
             cache["xk"], cache["xv"] = xkv
-    return x, prefix_len, cache, enc_kv, jnp.sum(aux)
+    return x, prefix_len, cache, enc_kv, jax.tree.map(jnp.sum, aux)
 
 
 def unembed(cfg, params, x, rc):
@@ -392,6 +421,11 @@ def decode_windowed(cfg: ModelConfig, params, cache, token, pos, rc):
     W = cfg.sliding_window
     grouped = jax.tree.map(
         lambda a: a.reshape(ng, per, *a.shape[1:]), params["layers"])
+    tables = rope_tables(cfg)
+    # the global layer's RoPE (YaRN where configured); local layers keep
+    # the default
+    glob_rope = None if tables is None else \
+        (jnp.asarray(tables[0][per - 1]), jnp.asarray(tables[1][per - 1]))
 
     def loc_body(x, sl):
         pl, ck, cv = sl
@@ -406,7 +440,7 @@ def decode_windowed(cfg: ModelConfig, params, cache, token, pos, rc):
         glob = jax.tree.map(lambda a: a[per - 1], pg)
         x, (ckl, cvl) = jax.lax.scan(loc_body, x, (loc, ckl, cvl))
         x, (ckg, cvg) = decode_attn_block(cfg, glob["attn"], x, 0, ckg,
-                                          cvg, pos, rc)
+                                          cvg, pos, rc, rope=glob_rope)
         x, _ = mlp_block(cfg, glob["mlp"], x, rc)
         return x, (ckl, cvl, ckg, cvg)
 
@@ -431,13 +465,18 @@ def decode(cfg: ModelConfig, params, cache, token, pos, rc):
         x = x + params["dec_pos"].astype(x.dtype)[pos][None, None]
     win = jnp.asarray(windows(cfg))
     has_cross = cfg.enc_layers > 0
+    tables = rope_tables(cfg)
 
     def body(x, sl):
+        rope = None
+        if tables is not None:
+            sl, rope = sl[:-1], sl[-1]
         if has_cross:
             pl, w, ck, cv, xk, xv = sl
         else:
             pl, w, ck, cv = sl
-        x, (ck, cv) = decode_attn_block(cfg, pl["attn"], x, w, ck, cv, pos, rc)
+        x, (ck, cv) = decode_attn_block(cfg, pl["attn"], x, w, ck, cv, pos,
+                                        rc, rope=rope)
         if has_cross:
             x = cross_attn_block(cfg, pl["xattn"], x, (xk, xv), rc)
         x, _ = mlp_block(cfg, pl["mlp"], x, rc)
@@ -446,6 +485,8 @@ def decode(cfg: ModelConfig, params, cache, token, pos, rc):
     xs = (params["layers"], win, cache["k"], cache["v"])
     if has_cross:
         xs = xs + (cache["xk"], cache["xv"])
+    if tables is not None:
+        xs = xs + (tuple(jnp.asarray(t) for t in tables),)
     x, (ck, cv) = jax.lax.scan(body, x, xs)
     new_cache = dict(cache, k=ck, v=cv)
     x = L.rms_norm(x, params["final_ln"], cfg.norm_eps)

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import metrics
 from repro.models import layers as L
 from repro.models import registry
 from repro.models.config import ModelConfig, RunConfig
@@ -121,6 +122,9 @@ def _ce_chunked(cfg, params, x, labels, rc):
 
 
 def loss_fn(cfg: ModelConfig, params, batch, rc: RunConfig):
+    """The batch's mean cross entropy, plus 0.01 times the MoE balance
+    term. A model with held experts returns (loss, counts): `counts` are
+    the step's STEP_COUNTS, summed over its layers."""
     x, prefix_len, _, _, aux = registry.forward(cfg, params, batch, rc)
     if prefix_len:
         x = x[:, prefix_len:]
@@ -129,9 +133,51 @@ def loss_fn(cfg: ModelConfig, params, batch, rc: RunConfig):
     else:
         logits = registry.unembed(cfg, params, x, rc)
         loss = _ce(logits, batch["labels"])
+    if cfg.experts_held:
+        return loss + 0.01 * aux["balance"], \
+            {k: aux[k] for k in step_counts(cfg)}
     if cfg.is_moe:
         loss = loss + 0.01 * aux
     return loss
+
+
+def step_counts(cfg: ModelConfig) -> tuple:
+    """Names of the counts a train step returns beside its loss: the
+    (token, expert) pairs a model's held experts computed."""
+    return ("held_pairs",) if cfg.experts_held else ()
+
+
+def _loss_and_grads(cfg: ModelConfig, rc: RunConfig, params, batch):
+    """loss_fn's loss, its counts ({} where it has none) and gradients."""
+    if step_counts(cfg):
+        (loss, counts), grads = jax.value_and_grad(
+            partial(loss_fn, cfg, rc=rc), has_aux=True)(params, batch)
+        return loss, counts, grads
+    loss, grads = jax.value_and_grad(
+        partial(loss_fn, cfg, rc=rc))(params, batch)
+    return loss, {}, grads
+
+
+class CountedStep:
+    """A jitted train step whose counts the host records: while the
+    profiler records, each call adds the step's counts to a `train.counts`
+    span (`repro.core.metrics`), waiting for the step to end to read
+    them. Otherwise the jitted function as it is."""
+
+    def __init__(self, fn, names: tuple):
+        self.fn, self.names = fn, names
+
+    def __call__(self, *args):
+        out = self.fn(*args)
+        if metrics.profiling():
+            counts = {k: int(out[2][k]) for k in self.names}
+            with metrics.span("train.counts"):
+                for k, n in counts.items():
+                    metrics.add(k, n)
+        return out
+
+    def __getattr__(self, name):
+        return getattr(self.fn, name)
 
 
 def _under_mesh(mesh, fn):
@@ -186,37 +232,41 @@ def build_train_step(cfg: ModelConfig, rc: RunConfig, mesh,
                 params)
 
         if nmb == 1:
-            loss, grads = jax.value_and_grad(
-                partial(loss_fn, cfg, rc=rc))(cast_once(params), batch)
+            loss, counts, grads = _loss_and_grads(cfg, rc, cast_once(params),
+                                                  batch)
         else:
             cparams = cast_once(params)
 
             def mb(carry, mbatch):
-                l, g = jax.value_and_grad(
-                    partial(loss_fn, cfg, rc=rc))(cparams, mbatch)
-                acc_l, acc_g = carry
-                return (acc_l + l,
+                l, c, g = _loss_and_grads(cfg, rc, cparams, mbatch)
+                acc_l, acc_c, acc_g = carry
+                return (acc_l + l, jax.tree.map(jnp.add, acc_c, c),
                         jax.tree.map(lambda a, b: a + b.astype(jnp.float32),
                                      acc_g, g)), None
 
             zero_g = jax.tree.map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), params)
-            (loss, grads), _ = jax.lax.scan(
-                mb, (jnp.zeros((), jnp.float32), zero_g), batch)
+            zero_c = {k: jnp.zeros((), jnp.int32) for k in counted}
+            (loss, counts, grads), _ = jax.lax.scan(
+                mb, (jnp.zeros((), jnp.float32), zero_c, zero_g), batch)
             loss = loss / nmb
             grads = jax.tree.map(lambda g: g / nmb, grads)
         new_params, new_opt, gnorm = adamw.apply_updates(
             opt, params, grads, opt_state)
-        metrics = {"loss": loss, "grad_norm": gnorm}
-        return new_params, new_opt, metrics
+        return new_params, new_opt, {"loss": loss, "grad_norm": gnorm,
+                                     **counts}
 
+    counted = step_counts(cfg)
     fn = jax.jit(
         _under_mesh(mesh, step),
         in_shardings=(param_specs, opt_specs, bspecs),
         out_shardings=(param_specs, opt_specs,
-                       {"loss": scalar, "grad_norm": scalar}),
+                       {"loss": scalar, "grad_norm": scalar,
+                        **{k: scalar for k in counted}}),
         donate_argnums=(0, 1),
     )
+    if counted:
+        fn = CountedStep(fn, counted)
 
     # jitted with the step's shardings, so each device builds only its
     # own shard and no leaf lands whole on one device first

@@ -172,3 +172,35 @@ def test_cells_cover_40_assignments():
     assert skipped == len(ARCHS) - len(LONG_OK)
     for arch in ARCHS:
         assert get_config(arch).name == arch
+
+
+@pytest.mark.parametrize("windowed", [False, True])
+def test_held_expert_yarn_decode_matches_forward(windowed):
+    """A 3:1 sliding/full mix with YaRN on the full layers and a held
+    share of the experts: decoding one token at a time, with the full
+    cache or the ring-buffered local one, gives the forward's logits."""
+    from repro.models.config import ModelConfig
+    cfg = ModelConfig(
+        name="held-yarn", family="transformer", n_layers=4, d_model=64,
+        n_heads=4, n_kv_heads=2, head_dim=16, vocab=256, rope_theta=5e5,
+        sliding_window=4, global_every=4, n_experts=16, top_k=4,
+        d_ff_expert=32, experts_held=4, expert_offset=4, yarn_factor=16.0,
+        yarn_original_max_pos=8, yarn_attention_factor=1.2772588722239782)
+    rc = RunConfig(seq_len=16, global_batch=2, kind="train",
+                   attn_impl="ref", compute_dtype="float32",
+                   param_dtype="float32", remat="none")
+    params = L.tree_init(registry.param_defs(cfg), jax.random.PRNGKey(0))
+    toks = jax.random.randint(jax.random.PRNGKey(7), (2, 8), 0, cfg.vocab)
+    x, _, _, _, _ = registry.forward(cfg, params, {"tokens": toks}, rc)
+    full_logits = registry.unembed(cfg, params, x, rc)
+    spec = registry.init_cache(cfg, 2, 16, jnp.float32, windowed=windowed)
+    cache = jax.tree.map(
+        lambda s: jnp.zeros(s[0], s[1]), spec,
+        is_leaf=lambda x: isinstance(x, tuple) and isinstance(x[0], tuple))
+    errs = []
+    for t in range(8):
+        lg, cache = registry.decode(cfg, params, cache, toks[:, t:t + 1],
+                                    jnp.asarray(t, jnp.int32), rc)
+        errs.append(np.abs(np.asarray(lg[:, 0]) -
+                           np.asarray(full_logits[:, t])).max())
+    assert max(errs) < 1e-3, errs

@@ -52,6 +52,24 @@ def test_reread_served_from_clean_cache_zero_rpcs():
     assert c.stats.counters["osc.cache_hit"] >= 8
 
 
+
+def test_unlocked_write_leaves_no_clean_pages():
+    """A write that took no lock (a rebuild's) keeps nothing cached after
+    its flush: no revocation could drop those pages and no read may
+    serve them. The same write under its lock stays cached."""
+    c = mk()
+    osc = c.make_oscs(c.make_client_rpc(0))[0]
+    a, b = osc.create(0)["oid"], osc.create(0)["oid"]
+    osc.writev(0, a, [(0, b"u" * 8192)], lock=False)
+    osc.writev(0, b, [(0, b"l" * 8192)])
+    osc.flush()
+    assert (0, a) not in osc.clean
+    assert osc.clean_bytes == 8192
+    base = reads(c)
+    assert osc.read(0, a, 0, 8192) == b"u" * 8192     # from the OST
+    assert osc.read(0, b, 0, 8192) == b"l" * 8192     # a hit
+    assert reads(c) == base + 1
+
 def test_cold_read_populates_cache():
     c = mk()
     w = c.make_oscs(c.make_client_rpc(0), writeback=False)[0]
