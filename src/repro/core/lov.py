@@ -153,10 +153,16 @@ def _r5_logical_size(lsm: StripeMd, slot_sizes: list) -> int:
     return best
 
 
+def _r5_file(lsm: StripeMd) -> tuple:
+    """A raid5 file's identity in the LOV's size cache: its objects."""
+    return tuple((o["ost"], o["group"], o["oid"]) for o in lsm.objects)
+
+
 class Lov:
     """Stripes over an ordered list of OSCs (one per OST)."""
 
     DEFAULT_STRIPE_SIZE = 1 << 20
+    R5_SIZES_MAX = 4096               # files whose degraded size is kept
 
     def __init__(self, oscs: list[osc_mod.Osc], group: int = 0,
                  policy: str = "round_robin",
@@ -170,6 +176,9 @@ class Lov:
         self.policy = policy
         self._rr = itertools.count()
         self.sim = oscs[0].sim if oscs else None
+        # degraded raid5 sizes: file -> (dead slot, the survivors' (lock,
+        # size) that vouched for it, logical size); see _r5_getattr
+        self._r5_sizes: dict[tuple, tuple] = {}
 
     # ------------------------------------------------------ admin state
     def is_active(self, uuid: str) -> bool:
@@ -360,6 +369,7 @@ class Lov:
         runs = _r5_chunks(lsm, offset, len(data))
         if not runs:
             return 0
+        self._r5_sizes.pop(_r5_file(lsm), None)
         ssz, cnt = lsm.stripe_size, lsm.stripe_count
         by_round: dict[int, dict] = {}
         for r, i, in_off, ln, lpos in runs:
@@ -483,37 +493,81 @@ class Lov:
                                   for s in range(len(lsm.objects))])
 
     def _r5_degraded_size(self, lsm: StripeMd, slot_sizes: list,
-                          dead: int) -> int:
-        """Logical size with one dead slot: survivors witness what they
-        can (`_r5_logical_size`); the dead slot may hold the logical
-        tail, so its unit in the last existing round is reconstructed
-        and its trailing-zero-trimmed length extends the estimate
-        (genuine trailing zeros in the tail unit are indistinguishable
-        from reconstruction padding — documented caveat)."""
+                          dead: int) -> tuple[int, int]:
+        """(logical size, bytes rebuilt) with one dead slot: survivors
+        witness what they can (`_r5_logical_size`); the dead slot may
+        hold the logical tail, so its unit in the last existing round is
+        reconstructed and its trailing-zero-trimmed length extends the
+        estimate (genuine trailing zeros in the tail unit are
+        indistinguishable from reconstruction padding — documented
+        caveat).  Every call rebuilds; `_r5_getattr` reuses the answer
+        under the survivors' PR locks."""
         ssz, cnt = lsm.stripe_size, lsm.stripe_count
         best = _r5_logical_size(lsm, slot_sizes)
         sizes = [s for s in slot_sizes if s]
         if not sizes:
-            return best
+            return best, 0
         for rr in range((max(sizes) - 1) // ssz, -1, -1):
             p = _r5_parity_slot(lsm, rr)
             if dead == p:
                 continue                  # parity unit: no logical bytes
             i = dead if dead < p else dead - 1
-            unit = self._r5_rebuild_slot_unit(lsm, rr, dead).rstrip(b"\0")
-            if unit:
-                best = max(best, ((rr * cnt) + i) * ssz + len(unit))
-            break    # lower rounds can't extend past a survivor witness
-        return best
+            unit = self._r5_rebuild_slot_unit(lsm, rr, dead)
+            tail = unit.rstrip(b"\0")
+            if tail:
+                best = max(best, ((rr * cnt) + i) * ssz + len(tail))
+            return best, len(unit)
+        return best, 0
+
+    def _r5_locked_degraded_size(self, lsm: StripeMd, attrs: list,
+                                 dead: int) -> int:
+        """The degraded size under the survivors' locks, rebuilt once per
+        lock grant.  A survivor's size is served under a held lock, named
+        by its handle, which no later lock reuses.  While every survivor
+        answers under the same PR locks as when the size was computed,
+        no survivor can have changed: a write to the dead unit's round
+        writes its parity or data on a survivor, and a punch cuts every
+        survivor, each under a PW lock that revokes the reader's PR.  A
+        survivor answered by a glimpse, the contended fall-back or a PW
+        lock vouches for nothing, and the size is rebuilt and not kept.
+        This LOV's own writes, punches and rebuilds drop the file's
+        entry, since a client's own PW locks leave its PR locks held."""
+        key = _r5_file(lsm)
+        vouch = tuple((a.get("lock"), a["size"])
+                      for s, a in enumerate(attrs) if s != dead)
+        held = all(lk is not None and lk[1] == "PR" for lk, _ in vouch)
+        got = self._r5_sizes.get(key)
+        if held and got is not None and got[:2] == (dead, vouch):
+            self.sim.stats.count("lov.degraded_size_cached")
+            size, rebuilt = got[2], 0
+        else:
+            self.sim.stats.count("lov.degraded_size_rebuilt")
+            size, rebuilt = self._r5_degraded_size(
+                lsm, [None if a is None else a["size"] for a in attrs], dead)
+            self._r5_sizes.pop(key, None)
+            if held:
+                if len(self._r5_sizes) >= self.R5_SIZES_MAX:
+                    del self._r5_sizes[next(iter(self._r5_sizes))]
+                self._r5_sizes[key] = (dead, vouch, size)
+        if metrics_mod.profiling():
+            metrics_mod.add("size_rebuilt_bytes", rebuilt)
+        return size
 
     def _r5_getattr(self, lsm: StripeMd, *, locked: bool) -> dict:
+        """size/mtime of a raid5 file from its slots' objects.  With one
+        slot dead the size needs the dead unit of the tail round rebuilt:
+        `locked` reuses it while the survivors' PR locks that vouched for
+        it are held (`_r5_locked_degraded_size`); unlocked, every call
+        rebuilds."""
         attrs = self._r5_slot_sizes(lsm, locked=locked)
         deadset = [s for s, a in enumerate(attrs) if a is None]
         if len(deadset) > 1:
             raise R.RpcError(-5, "raid5: multiple OST failures")
         sizes = [None if a is None else a["size"] for a in attrs]
-        if deadset:
-            size = self._r5_degraded_size(lsm, sizes, deadset[0])
+        if deadset and locked:
+            size = self._r5_locked_degraded_size(lsm, attrs, deadset[0])
+        elif deadset:
+            size, _ = self._r5_degraded_size(lsm, sizes, deadset[0])
         else:
             size = _r5_logical_size(lsm, sizes)
         live = [a for a in attrs if a is not None]
@@ -546,9 +600,16 @@ class Lov:
         """Truncate: punch every object to its per-slot size, then
         recompute the (now shorter) tail round's parity.  Best-effort
         on a dead OST — the rebuild regenerates a punched object from
-        the post-punch parity anyway."""
+        the post-punch parity anyway.  The tail round's units are read
+        BEFORE the cut: a dead unit is rebuilt from the old parity,
+        which matches only the survivors as they were."""
         from repro.kernels import ops
         ssz, cnt = lsm.stripe_size, lsm.stripe_count
+        self._r5_sizes.pop(_r5_file(lsm), None)
+        if size > 0:
+            r = (size - 1) // (ssz * cnt)     # tail round
+            units = [self._r5_unit_data(lsm, r, i)[
+                :max(0, size - (r * cnt + i) * ssz)] for i in range(cnt)]
         for s, o in enumerate(lsm.objects):
             try:
                 self.by_uuid[o["ost"]].punch(
@@ -560,8 +621,6 @@ class Lov:
                 self.sim.stats.count("lov.degraded_punch")
         if size <= 0:
             return
-        r = (size - 1) // (ssz * cnt)     # tail round
-        units = [self._r5_unit_data(lsm, r, i) for i in range(cnt)]
         live = [u for u in units if u]
         if not live:
             return
@@ -586,6 +645,7 @@ class Lov:
                      if o["ost"] == dead_uuid), None)
         if dead is None:
             return None
+        self._r5_sizes.pop(_r5_file(lsm), None)
         ssz = lsm.stripe_size
         grp = lsm.objects[dead]["group"]
         attrs = self._r5_slot_sizes(lsm)
@@ -593,7 +653,7 @@ class Lov:
             raise R.RpcError(-5, "raid5: second OST failure during rebuild")
         sizes = [None if s == dead else a["size"]
                  for s, a in enumerate(attrs)]
-        logical = self._r5_degraded_size(lsm, sizes, dead)
+        logical, _ = self._r5_degraded_size(lsm, sizes, dead)
         osize = self._r5_obj_size_for(lsm, dead, logical)
         new = spare_osc.create(grp)
         self.by_uuid.setdefault(spare_osc.uuid, spare_osc)
@@ -688,7 +748,11 @@ class Lov:
         client-side ordering rule of §6.2.3; real Lustre uses glimpse
         ASTs — a PR enqueue is our simpler equivalent). Served from the
         cached locks' value blocks (§7.7) when possible: a warm
-        sequential reader pays ZERO RPCs for its size checks."""
+        sequential reader pays ZERO RPCs for its size checks.  A raid5
+        file with a dead slot also reuses its rebuilt size while the
+        survivors' PR locks that vouched for it are held; this LOV's own
+        writes, punches and rebuilds of the file drop it, as does any
+        revocation of those locks (`_r5_locked_degraded_size`)."""
         if lsm.pattern == "raid5":
             return self._r5_getattr(lsm, locked=True)
         outs = self.sim.parallel([

@@ -226,6 +226,11 @@ class Osc:
             "setattr", {"group": group, "oid": oid, "attrs": attrs}).data
 
     def punch(self, group: int, oid: int, size: int):
+        """Truncate the object to `size` under a PW lock on [size, EOF),
+        as a write takes one on its extent: other clients' locks over the
+        cut are revoked, so none of them keeps serving the old size from
+        its value block or the cut bytes from its clean cache."""
+        self.lock(group, oid, "PW", (size, dlm_mod.MAX_EXT))
         self._drop_dirty_beyond(group, oid, size)
         self._invalidate_clean(group, oid, (size, dlm_mod.MAX_EXT))
         key = (group, oid)
@@ -754,10 +759,13 @@ class Osc:
         RPCs on the warm path. The cold enqueue is a GLIMPSE enqueue: a
         conflicting writer is ASKED for its LVB via a glimpse AST instead
         of revoked, so a stat of a file under write no longer kills the
-        writer's write-back cache (the ROADMAP'd 'glimpse ASTs proper')."""
+        writer's write-back cache (the ROADMAP'd 'glimpse ASTs proper').
+        An answer served under a held lock names it, as `lock`: (handle,
+        mode); the glimpse and the fall-back answers name none."""
         key = (group, oid)
-        if key not in self._sizes or self.locks.match(
-                self._res(group, oid), "PR", dlm_mod.WHOLE) is None:
+        lk = None if key not in self._sizes else self.locks.match(
+            self._res(group, oid), "PR", dlm_mod.WHOLE)
+        if lk is None:
             lk, lvb = self.lock(group, oid, "PR", glimpse=True)
             if lk is None and "size" in lvb:
                 # writer active: the server merged the holders' glimpse
@@ -777,7 +785,7 @@ class Osc:
             if (d.group, d.oid) == key:
                 size = max(size, d.end)
                 mtime = max(mtime, d.mtime)
-        return {"size": size, "mtime": mtime}
+        return {"size": size, "mtime": mtime, "lock": (lk.handle, lk.mode)}
 
     def _read_via(self, ref: dict, group, oid, offset, length) -> bytes:
         imp = self._cobd_imports.get(ref["uuid"])

@@ -465,3 +465,181 @@ def test_raid1_hedged_read_takes_dirty_aware_path():
     assert r.read_hedged(oid, 0, 8) == b"22222222"   # never b's zeros
     assert r.resync() == 1
     assert b.read(0, oid, 0, 8) == b"22222222"
+
+
+# ------------------------- raid5: degraded size kept under the PR locks
+
+from repro.fsio import LustreClient  # noqa: E402
+
+SSZ5, CNT5 = 512, 2
+TAIL = 3 * SSZ5 * CNT5 + SSZ5 + 300    # round 3: unit 0 full, unit 1 part
+
+
+def _deg_counts(c):
+    got = c.stats.counters
+    return (got.get("lov.degraded_size_rebuilt", 0),
+            got.get("lov.degraded_size_cached", 0))
+
+
+def _degraded_file(clients=3):
+    """A raid5 file whose tail lies in the dead slot: unit 1 of round 3
+    holds the last 300 bytes, with unit 0 full, so a change inside the
+    dead unit leaves every survivor's size as it was. The writer's locks
+    are gone (a first reader revoked them); returns (cluster, data, dead
+    OST uuid, mount), the mount making a client with the OST inactive."""
+    c = LustreCluster(osts=3, mdses=1, clients=clients, commit_interval=32,
+                      spare_osts=1)
+    w = LustreClient(c, 0).mount()
+    fh = w.creat("/f", stripe_count=CNT5, stripe_size=SSZ5, pattern="raid5")
+    data = _r5_payload(TAIL, seed=11)
+    w.write(fh, data, offset=0)
+    w.close(fh)
+    dead = fh.lsm.objects[LV._r5_slot(fh.lsm, 3, 1)]["ost"]
+    c.fail_node(c.target(dead).node.name)
+
+    def mount(i):
+        fs = LustreClient(c, i).mount()
+        fs.deactivate_ost(dead)
+        return fs
+
+    first = mount(0)
+    assert first.read(first.open("/f"), TAIL, offset=0) == data
+    return c, data, dead, mount
+
+
+def test_raid5_degraded_reader_rebuilds_the_tail_once_per_lock_grant():
+    c, data, _, mount = _degraded_file()
+    for grant in range(2):                # each mount takes its own locks
+        r = mount(1 + grant)
+        rh = r.open("/f")
+        base = _deg_counts(c)
+        units = c.stats.counters.get("lov.reconstruct_unit", 0)
+        got = b"".join(r.read(rh, SSZ5) for _ in range(TAIL // SSZ5 + 1))
+        assert got == data
+        reads = TAIL // SSZ5 + 1
+        rebuilt, cached = (a - b for a, b in zip(_deg_counts(c), base))
+        assert (rebuilt, cached) == (1, reads - 1)
+        # the size checks rebuilt one unit; the reads rebuilt the dead
+        # slot's data units, once each
+        dead_data = sum(LV._r5_slot(rh.lsm, rr, i) == LV._r5_slot(
+            rh.lsm, 3, 1) for rr in range(4) for i in range(CNT5))
+        assert c.stats.counters["lov.reconstruct_unit"] - units == \
+            1 + dead_data
+
+
+def _other_extends(w, fh, data):
+    more = _r5_payload(100, seed=12)
+    w.write(fh, more, offset=len(data))   # still inside the dead unit
+    return data + more
+
+
+def _other_overwrites(w, fh, data):
+    start = 3 * SSZ5 * CNT5
+    new = _r5_payload(len(data) - start, seed=13)
+    w.write(fh, new, offset=start)
+    return data[:start] + new
+
+
+def _other_punches(w, fh, data):
+    w.truncate("/f", len(data) - 200)     # cut inside the dead unit
+    return data[:-200]
+
+
+def _other_punches_a_round(w, fh, data):
+    cut = 2 * SSZ5 * CNT5 + 100           # cuts the survivors too
+    w.truncate("/f", cut)
+    return data[:cut]
+
+
+@pytest.mark.parametrize("change", [_other_extends, _other_overwrites,
+                                    _other_punches, _other_punches_a_round])
+def test_raid5_degraded_size_follows_another_clients_change(change):
+    c, data, _, mount = _degraded_file()
+    r = mount(1)
+    rh = r.open("/f")
+    assert r.read(rh, TAIL, offset=0) == data
+    assert r.lov.getattr_locked(rh.lsm)["size"] == TAIL
+    assert _deg_counts(c)[1] >= 1         # the reader holds a kept size
+    w = mount(2)
+    want = change(w, w.open("/f", "w"), data)
+    assert r.lov.getattr_locked(rh.lsm)["size"] == len(want)
+    assert r.read(rh, 2 * TAIL, offset=0) == want
+
+
+def _own_extends(r, fh, data):
+    more = _r5_payload(100, seed=14)
+    r.write(fh, more, offset=len(data))
+    return data + more
+
+
+def _own_punches(r, fh, data):
+    r.truncate("/f", len(data) - 200)
+    return data[:-200]
+
+
+@pytest.mark.parametrize("change", [_own_extends, _own_punches])
+def test_raid5_degraded_size_follows_the_readers_own_change(change):
+    # a client's own PW locks leave its PR locks held: only the LOV's
+    # own drop of the kept size makes the change visible
+    c, data, _, mount = _degraded_file()
+    r = mount(1)
+    rh = r.open("/f", "w")
+    assert r.read(rh, TAIL, offset=0) == data
+    assert r.lov.getattr_locked(rh.lsm)["size"] == TAIL
+    want = change(r, rh, data)
+    rebuilt = _deg_counts(c)[0]
+    assert r.lov.getattr_locked(rh.lsm)["size"] == len(want)
+    assert _deg_counts(c)[0] == rebuilt + 1
+    assert r.read(rh, 2 * TAIL, offset=0) == want
+
+
+def test_raid5_degraded_size_after_rebuild_and_layout_swap():
+    c, data, dead, mount = _degraded_file()
+    r = mount(1)
+    rh = r.open("/f")
+    assert r.read(rh, TAIL, offset=0) == data
+    old = rh.lsm
+    # this LOV rebuilds the file itself: its kept size goes with the
+    # old layout, and the new layout (no dead slot) sizes from witnesses
+    new = r.lov.rebuild_object(old, dead, r.lov.by_uuid[c.spare_uuids[0]])
+    assert LV._r5_file(old) not in r.lov._r5_sizes
+    assert r.lov.getattr_locked(new)["size"] == TAIL
+    assert r.lov.read(new, 0, TAIL) == data
+    # another client's rebuild and swap: the reader's handle follows it
+    c2, data2, dead2, mount2 = _degraded_file(clients=4)
+    r2 = mount2(1)
+    rh2 = r2.open("/f")
+    assert r2.read(rh2, TAIL, offset=0) == data2
+    rep = mount2(2).rebuild_ost(dead2, c2.spare_uuids[0])
+    assert rep["swapped"] == 1
+    more = _r5_payload(100, seed=15)
+    w = LustreClient(c2, 3).mount()
+    w.write(w.open("/f", "w"), more, offset=TAIL)
+    assert r2.read(rh2, 2 * TAIL, offset=0) == data2 + more
+    assert r2.lov.getattr_locked(rh2.lsm)["size"] == TAIL + 100
+
+
+def _unlocked(r, lsm):
+    return r.lov.getattr(lsm)["size"]
+
+
+def _glimpsed(r, lsm):
+    # a writer holds PW locks on every survivor: each size check is
+    # answered by glimpses, under no lock of the reader's
+    return r.lov.getattr_locked(lsm)["size"]
+
+
+@pytest.mark.parametrize("size_of", [_unlocked, _glimpsed])
+def test_raid5_degraded_size_without_held_locks_rebuilds_every_call(size_of):
+    c, data, _, mount = _degraded_file()
+    w = mount(1)
+    fh = w.open("/f", "w")
+    w.write(fh, data[:SSZ5 * CNT5 * 4], offset=0)   # PW on every survivor
+    r = mount(2)
+    lsm = r.open("/f").lsm
+    for _ in range(3):
+        units = c.stats.counters.get("lov.reconstruct_unit", 0)
+        assert size_of(r, lsm) == TAIL
+        assert c.stats.counters["lov.reconstruct_unit"] == units + 1
+    assert _deg_counts(c)[1] == 0
+    assert not r.lov._r5_sizes
